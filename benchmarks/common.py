@@ -59,11 +59,14 @@ def timeit(fn, *args, warmup: int = 1, iters: int = 5) -> float:
 
 def run_subprocess(code: str, device_count: int | None = None,
                    timeout: int = 1200) -> str:
-    """Run python code in a clean subprocess (optionally with N fake host
-    devices) and return stdout.  Benchmarks needing multiple devices use
-    this so the parent keeps its 1-device view."""
+    """Run python code in a clean subprocess on the CPU (optionally with N
+    fake host devices) and return stdout.  Benchmarks needing multiple
+    devices use this so the parent keeps its 1-device view.  The child is
+    held to the CPU because a parent that has imported JAX may hold the
+    accelerator, and a chip belongs to one process at a time."""
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    env["JAX_PLATFORMS"] = "cpu"
     if device_count:
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={device_count}")

@@ -5,10 +5,13 @@ other congruent slots is (nearly) free via bitstream manipulation.  Standard
 flow: compile the module separately for *each* region.
 
 FOS-JAX measurement (subprocess with 8 host devices, shell host8_s4):
-  - xilinx-flow analogue: place the module on slots 0..2 with a cold
-    compilation cache each time  -> 3 full compiles;
+  - xilinx-flow analogue: place the module on slots 0..2 with JAX's
+    caches cleared before each placement -> 3 full compiles;
   - FOS analogue: first compile (against the congruence class), then
-    relocations to slots 1..2 with the XLA compilation cache warm.
+    relocations to slots 1..2 with the in-process caches warm.
+The persistent compilation cache is off throughout, so every cold compile
+is cold.  A CPU-only tool: the times are XLA CPU compile times, not chip
+compile times.
 Derived figure = speedup of the FOS flow for 3 regions (paper: 1.74-2.34x).
 """
 from __future__ import annotations
@@ -16,11 +19,9 @@ from __future__ import annotations
 from benchmarks.common import row, run_subprocess
 
 _CODE = r"""
-import time, json, tempfile, os
+import time, json
 import jax
-jax.config.update("jax_compilation_cache_dir", tempfile.mkdtemp())
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+jax.config.update("jax_enable_compilation_cache", False)
 from repro.core import Shell, uniform_shell
 from repro.core.module import AccelModule
 from repro.core import zoo
@@ -31,12 +32,14 @@ results = {}
 # --- standard-flow analogue: independent compile per region (cold caches) ---
 t_cold = []
 for i in range(3):
+    jax.clear_caches()
     mod = AccelModule(f"mandel_cold_{i}", zoo.build_mandelbrot, [1])
     t0 = time.perf_counter()
     mod.place(shell.slots[i], 1)
     t_cold.append(time.perf_counter() - t0)
 
 # --- FOS flow: compile once, relocate to congruent slots (warm cache) ------
+jax.clear_caches()
 mod = AccelModule("mandel_fos", zoo.build_mandelbrot, [1])
 t0 = time.perf_counter(); mod.place(shell.slots[0], 1)
 t_first = time.perf_counter() - t0

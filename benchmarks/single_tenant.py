@@ -1,5 +1,6 @@
 """Paper Fig. 19-21 analogue: single-tenant scaling with replication and
-varying exposed parallelism.
+varying exposed parallelism.  A CPU-only tool: its live part runs on
+fake host devices, and its times are CPU times, not device times.
 
 Two layers of evidence (this container has ONE physical core, so concurrent
 slot execution timeshares it — live wall-clock cannot show parallel

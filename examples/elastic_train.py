@@ -17,6 +17,7 @@ import tempfile
 
 sys.path.insert(0, "src")
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.train import TrainRun, train               # noqa: E402
 
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--m100", action="store_true",
                     help="~100M-param config (CPU-slow)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.m100:
         # ~100M params: register an ad-hoc config based on llama3.2-3b

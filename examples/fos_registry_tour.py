@@ -17,9 +17,11 @@ import numpy as np                                            # noqa: E402
 
 from repro.core import Shell, default_registry, uniform_shell  # noqa: E402
 from repro.core.module import AccelModule, run_placement       # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     reg = default_registry()
 
     print("== shell descriptor (paper Listing 1) ==")

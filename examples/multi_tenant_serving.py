@@ -37,7 +37,8 @@ import numpy as np                                            # noqa: E402
 
 from repro.core import Daemon, FabricDescriptor, ImplAlt, \
     ModuleDescriptor, PolicyConfig, QoSContract, Shell, \
-    default_registry, uniform_shell                           # noqa: E402
+    default_registry, lm_forward_descriptor, uniform_shell    # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.obs import FlightRecorder, export_chrome_trace     # noqa: E402
 
 
@@ -62,7 +63,11 @@ def build_shells(reg):
 
 
 def main():
+    enable_compile_cache()
     reg = default_registry()
+    # the CPU-sized lm-forward (2-layer granite REDUCED, 8 x 64 tokens);
+    # chip_smoke.py serves the full-width one on a chip
+    reg.register_module(lm_forward_descriptor(reduced=True, seq=64))
     shells = build_shells(reg)
     # preemptive priority policy with checkpointing: carol's LM forward
     # is latency-sensitive (priority 3 + deadline); alice/bob run as

@@ -10,6 +10,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro import configs                                    # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.serve import ServeRun, serve               # noqa: E402
 from repro.launch.train import TrainRun, train               # noqa: E402
 
@@ -20,6 +21,7 @@ def main():
                     choices=configs.ARCH_IDS)
     ap.add_argument("--steps", type=int, default=20)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print(f"== training {args.arch} (reduced) for {args.steps} steps ==")
     hist = train(TrainRun(arch=args.arch, steps=args.steps, global_batch=8,

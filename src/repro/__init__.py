@@ -1,40 +1,1 @@
-"""repro: FOS-on-JAX reproduction package.
-
-Importing the package backfills a few newer-jax APIs that the codebase
-targets but the container's pinned jax predates.  Each backfill delegates
-to the stable equivalent and is skipped when the real API exists:
-
-  - jax.tree.flatten_with_path / map_with_path  (jax.tree_util.*)
-  - jax.set_mesh          (context form only; Mesh is a context manager)
-  - jax.shard_map         (jax.experimental.shard_map; check_vma->check_rep)
-  - pallas tpu CompilerParams                   (TPUCompilerParams)
-"""
-import jax as _jax
-import jax.tree_util as _tu
-
-if not hasattr(_jax.tree, "flatten_with_path"):
-    _jax.tree.flatten_with_path = _tu.tree_flatten_with_path
-if not hasattr(_jax.tree, "map_with_path"):
-    _jax.tree.map_with_path = _tu.tree_map_with_path
-
-if not hasattr(_jax, "set_mesh"):
-    # every call site uses `with jax.set_mesh(mesh): ...`; on older jax the
-    # Mesh object itself is the context manager that sets the ambient mesh
-    _jax.set_mesh = lambda mesh: mesh
-
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=True, **kwargs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
-
-    _jax.shard_map = _compat_shard_map
-
-try:
-    import jax.experimental.pallas.tpu as _pltpu
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pallas optional on some backends
-    pass
+"""repro: FOS-on-JAX reproduction package."""

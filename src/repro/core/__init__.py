@@ -30,6 +30,21 @@ from repro.core.slo import ADMIT, AdmissionController, \
     AdmissionRejected, AdmissionVerdict, DEGRADE, QoSContract, REJECT
 
 
+def lm_forward_descriptor(**builder_args) -> ModuleDescriptor:
+    """The `lm-forward` module: granite-3-8b at its published widths
+    (`zoo.build_lm_forward`).  `builder_args` go to the builder through
+    the descriptor's meta; CPU tests and examples pass `reduced=True`."""
+    # lm-forward carries large activation state: its context save/restore
+    # is priced above the policy default (ImplAlt.meta overrides)
+    ckpt = {"ckpt_save_ms": 2.0, "ckpt_restore_ms": 2.0}
+    return ModuleDescriptor(
+        name="lm-forward", entrypoint="repro.core.zoo:build_lm_forward",
+        impls=(ImplAlt("x1", 1, 20.0, meta=dict(ckpt)),
+               ImplAlt("x2", 2, 11.0, meta=dict(ckpt))),
+        kind="fn",
+        meta={"builder_args": builder_args} if builder_args else {})
+
+
 def default_registry() -> Registry:
     """Registry preloaded with the benchmark accelerator zoo."""
     reg = Registry()
@@ -46,15 +61,7 @@ def default_registry() -> Registry:
     reg.register_module(ModuleDescriptor(
         name="matmul", entrypoint="repro.core.zoo:build_matmul",
         impls=(ImplAlt("x1", 1, 4.0), ImplAlt("x2", 2, 2.3)), kind="fn"))
-    # lm-forward carries large activation state: its context save/restore
-    # is priced above the policy default (ImplAlt.meta overrides)
-    reg.register_module(ModuleDescriptor(
-        name="lm-forward", entrypoint="repro.core.zoo:build_lm_forward",
-        impls=(ImplAlt("x1", 1, 20.0,
-                       meta={"ckpt_save_ms": 2.0, "ckpt_restore_ms": 2.0}),
-               ImplAlt("x2", 2, 11.0,
-                       meta={"ckpt_save_ms": 2.0, "ckpt_restore_ms": 2.0})),
-        kind="fn"))
+    reg.register_module(lm_forward_descriptor())
     # example multi-shell fabrics (Fabric.from_registry(reg, name))
     reg.register_fabric(FabricDescriptor("pod512", ("pod256_s4",
                                                     "pod256_s8")))

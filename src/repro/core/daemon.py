@@ -35,6 +35,7 @@ checkpoint-aware either way.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -106,11 +107,21 @@ class Daemon:
         self._results: dict[int, list] = {}
         self._handles: dict[int, JobHandle] = {}
         self._cancelled: set[int] = set()     # aids of preempted assignments
+        # one lock per slot, held by the worker running on it: a preempted
+        # assignment still running on its chips ends before the next one
+        # starts there.  Multi-chip programs launched on the same chips
+        # from two threads at once may reach the chips in different orders.
+        self._slot_locks = {(name, i): threading.Lock()
+                            for name, s in self.shells.items()
+                            for i in range(len(s.slots))}
         self._pool = ThreadPoolExecutor(max_workers=max_workers)
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self.stats = {"reconfigurations": 0, "reuses": 0, "chunks": 0,
                       "preemptions": 0, "sched_ns": 0, "sched_calls": 0}
+        # per module: placements installed, and their compile and
+        # weight-init seconds summed
+        self.module_stats: dict[str, dict] = {}
         self._thread.start()
 
     @property
@@ -130,6 +141,9 @@ class Daemon:
 
         - ``daemon``: executor counters (reconfigurations, reuses,
           chunks, preemptions, scheduling-pass timing);
+        - ``modules``: per module, placements installed, the seconds
+          compiling their programs (``compile_s``) and compiling and
+          running their on-slot weight inits (``init_s``);
         - ``ckpt``: checkpoint counters when `PolicyConfig.ckpt` is on;
         - ``slo``: per-tenant SLO attainment once any `QoSContract` is
           registered;
@@ -144,6 +158,8 @@ class Daemon:
             fab = self.fabric
             m = {
                 "daemon": dict(self.stats),
+                "modules": {name: dict(v)
+                            for name, v in self.module_stats.items()},
                 "ckpt": (dict(fab.ckpt.stats)
                          if fab.ckpt is not None else {}),
                 "slo": (fab.slo.attainment()
@@ -260,6 +276,16 @@ class Daemon:
                     and not a.reconfigure:
                 self.stats["reuses"] += 1
                 return pl
+            if a.aid in self.fabric.states[shell_name].active:
+                # the range is reconfigured: drop every placement that
+                # overlaps it first, so the outgoing module's weights are
+                # freed before the incoming module's are built on the
+                # same chips
+                lo, hi = a.rng.start, a.rng.start + a.rng.size
+                for k in [k for k in self._placements
+                          if k[0] == shell_name and k[1] < hi
+                          and lo < k[1] + k[2]]:
+                    del self._placements[k]
         mod = self._module(a.module)
         shell = self.shells[shell_name]
         slot = (shell.slots[a.rng.start] if a.rng.size == 1 else
@@ -271,6 +297,12 @@ class Daemon:
             if a.aid in self.fabric.states[shell_name].active:
                 self._placements[key] = pl
                 self.stats["reconfigurations"] += 1
+                ms = self.module_stats.setdefault(
+                    a.module, {"placements": 0, "compile_s": 0.0,
+                               "init_s": 0.0})
+                ms["placements"] += 1
+                ms["compile_s"] += pl.compile_time_s
+                ms["init_s"] += pl.init_time_s
         return pl
 
     # -- event loop -------------------------------------------------------------
@@ -351,6 +383,12 @@ class Daemon:
                 return
 
     def _run_assignment(self, shell_name: str, a: Assignment):
+        with contextlib.ExitStack() as held:
+            for i in sorted(a.rng.slots):        # one order: no deadlock
+                held.enter_context(self._slot_locks[(shell_name, i)])
+            self._run_on_slots(shell_name, a)
+
+    def _run_on_slots(self, shell_name: str, a: Assignment):
         with self._lock:
             if a.aid in self._cancelled:   # preempted before we started
                 self._cancelled.discard(a.aid)

@@ -5,14 +5,18 @@ FOS mapping:
     isolation from the shell instance -> decoupled compilation;
   - placement into a congruent slot re-lowers against that slot's devices
     with the XLA compilation cache warm -> relocation (BitMan analogue);
-  - weight transfer to the slot's devices = partial reconfiguration; the
+  - weights onto the slot's devices = partial reconfiguration; the
     scheduler skips it when the module is already resident (paper 4.4.3).
+    Weights are random and seeded, generated on the slot in its sharding
+    by a compiled init, so no other copy stays behind.  That init stands
+    in for a checkpoint load, which nothing here measures yet.
 
 A ModuleBuilder (referenced by the registry descriptor's entrypoint) returns
 a ModuleProgram describing fn / abstract inputs / shardings / weights.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable
@@ -31,7 +35,7 @@ class ModuleProgram:
     weight_pspecs: Any                   # PartitionSpec pytree (or None)
     input_pspecs: tuple                  # PartitionSpec pytrees
     output_pspecs: Any = None
-    init_weights: Callable | None = None  # key -> concrete weights (host)
+    init_weights: Callable | None = None  # key -> weights (jit-able)
 
     def signature(self) -> dict:
         def leaf(s):
@@ -50,8 +54,8 @@ class Placement:
     slot: Slot
     executable: Any
     weights_on_slot: Any
-    load_time_s: float
-    compile_time_s: float
+    init_time_s: float       # compile and run the on-slot weight init
+    compile_time_s: float    # compile the module's program alone
     cache_hit: bool
 
 
@@ -64,7 +68,6 @@ class AccelModule:
         self.builder = builder
         self.footprints = list(footprints)
         self._programs: dict[tuple, ModuleProgram] = {}
-        self._host_weights: dict[int, Any] = {}
         self._compile_count = 0
         self._compile_keys: set[tuple] = set()
         self.weights_key = weights_key
@@ -77,20 +80,11 @@ class AccelModule:
             self._programs[key] = self.builder(slot.mesh, footprint)
         return self._programs[key]
 
-    def host_weights(self, footprint: int):
-        if footprint not in self._host_weights:
-            prog = next(iter(self._programs.values()), None)
-            assert prog is not None, "compile before requesting weights"
-            if prog.init_weights is None:
-                self._host_weights[footprint] = None
-            else:
-                self._host_weights[footprint] = prog.init_weights(
-                    jax.random.PRNGKey(self.weights_key))
-        return self._host_weights[footprint]
-
     def place(self, slot: Slot, footprint: int) -> Placement:
-        """Compile (cache-mediated) + load weights onto the slot."""
-        from jax.sharding import NamedSharding
+        """Compile (cache-mediated) + build the weights on the slot."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.launch.compile_cache import persistent_cache_off
 
         prog = self.program(slot, footprint)
         mesh = slot.mesh
@@ -100,30 +94,34 @@ class AccelModule:
         w_sh = (jax.tree.map(lambda p: NamedSharding(mesh, p),
                              prog.weight_pspecs)
                 if prog.weight_pspecs is not None else None)
-        t0 = time.perf_counter()
+        key = jax.device_put(jax.random.PRNGKey(self.weights_key),
+                             NamedSharding(mesh, PartitionSpec()))
         args = (prog.abstract_weights, *prog.abstract_inputs)
         shardings = (w_sh, *in_sh) if w_sh is not None else (None, *in_sh)
-        jitted = jax.jit(prog.fn, in_shardings=shardings)
-        lowered = jitted.lower(*args)
-        executable = lowered.compile()
-        t1 = time.perf_counter()
+        # A multi-chip executable read back from the persistent cache
+        # halted TPU v5e chips ("Core halted unexpectedly"); compiled
+        # afresh it ran.  Programs spanning several devices stay out of it.
+        uncached = (persistent_cache_off() if mesh.devices.size > 1
+                    else contextlib.nullcontext())
+        with uncached:
+            t0 = time.perf_counter()
+            executable = jax.jit(prog.fn, in_shardings=shardings) \
+                .lower(*args).compile()
+            t1 = time.perf_counter()
+            # the weights are generated on the slot's devices in their
+            # sharding, never staged elsewhere
+            w_dev = (jax.block_until_ready(
+                jax.jit(prog.init_weights, out_shardings=w_sh)(key))
+                if prog.init_weights is not None else None)
+            t2 = time.perf_counter()
         # congruence-class cache bookkeeping: a repeat compile of the same
         # (program, congruence) is a relocation, not a fresh compile
         ckey = (slot.congruence_key, footprint)
         cache_hit = ckey in self._compile_keys
         self._compile_keys.add(ckey)
         self._compile_count += 1
-        # weight transfer = partial reconfiguration
-        hw = self.host_weights(footprint)
-        t2 = time.perf_counter()
-        if hw is not None and w_sh is not None:
-            w_dev = jax.device_put(hw, w_sh)
-            jax.block_until_ready(w_dev)
-        else:
-            w_dev = None
-        t3 = time.perf_counter()
         return Placement(self, footprint, slot, executable, w_dev,
-                         load_time_s=t3 - t2, compile_time_s=t1 - t0,
+                         init_time_s=t2 - t1, compile_time_s=t1 - t0,
                          cache_hit=cache_hit)
 
 
@@ -137,8 +135,5 @@ def run_placement(placement: Placement, *chunk_args):
     for a, ps in zip(chunk_args, prog.input_pspecs):
         sh = jax.tree.map(lambda p: NamedSharding(mesh, p), ps)
         args.append(jax.device_put(a, sh))
-    if placement.weights_on_slot is not None:
-        out = placement.executable(placement.weights_on_slot, *args)
-    else:
-        out = placement.executable(None, *args)
+    out = placement.executable(placement.weights_on_slot, *args)
     return jax.block_until_ready(out)

@@ -8,6 +8,7 @@ swapped without touching any other component.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import json
 from pathlib import Path
@@ -49,7 +50,9 @@ class ModuleDescriptor:
     the analogue of the bitstream file reference.  `registers` (the ADR-map
     analogue) is the module's abstract I/O signature, auto-filled at first
     compile, which the daemon's generic driver uses to invoke any module
-    without module-specific host code.
+    without module-specific host code.  `meta["builder_args"]`, when
+    present, holds keyword arguments the builder is called with (for
+    example the size a module is served at).
     """
     name: str
     entrypoint: str
@@ -83,7 +86,9 @@ class ModuleDescriptor:
 
     def load_builder(self):
         mod, _, fn = self.entrypoint.partition(":")
-        return getattr(importlib.import_module(mod), fn)
+        builder = getattr(importlib.import_module(mod), fn)
+        args = self.meta.get("builder_args")
+        return functools.partial(builder, **args) if args else builder
 
 
 def parse_transfer_pair(key, shells) -> tuple[str, str]:

@@ -4,7 +4,7 @@ These are the FOS-JAX analogues of the paper's case-study accelerators:
   - mandelbrot : compute-bound fractal iteration (paper section 5.5)
   - sobel      : memory-bound 3x3 stencil (paper section 5.5)
   - matmul     : generic dense kernel (spector-style)
-  - lm_forward : a reduced-config LM forward step from the model zoo
+  - lm_forward : granite-3-8b forward at its published widths
 
 Each builder(mesh, footprint) -> ModuleProgram.  Bigger footprints map to
 wider data-parallel slots; implementation alternatives additionally scale
@@ -106,13 +106,20 @@ def build_matmul(mesh, footprint: int, *, m: int = 512, k: int = 512,
         init_weights=init)
 
 
-def build_lm_forward(mesh, footprint: int, *, arch: str = "llama3.2-3b",
-                     batch: int = 8, seq: int = 64) -> ModuleProgram:
-    """Reduced-config LM teacher-forced forward (module-zoo integration)."""
-    from repro import configs
+def build_lm_forward(mesh, footprint: int, *, reduced: bool = False,
+                     batch: int = 8, seq: int = 512) -> ModuleProgram:
+    """granite-3-8b teacher-forced forward over a [batch, seq] token chunk,
+    returning the last position's logits [batch, padded vocab].
+
+    Serves `configs.granite_3_8b.SERVED` (every width as published, 16
+    layers, bf16 weights; the cuts are listed beside it).  `reduced=True`
+    serves the 2-layer REDUCED config instead, for CPU tests and examples
+    (pass it through the descriptor: `lm_forward_descriptor(reduced=True)`).
+    """
+    from repro.configs import granite_3_8b
     from repro.models import api, stack
 
-    cfg = configs.get(arch, reduced=True)
+    cfg = granite_3_8b.REDUCED if reduced else granite_3_8b.SERVED
     axis = _data_axis(mesh)
 
     def fn(params, tokens):

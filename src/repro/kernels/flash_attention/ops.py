@@ -19,12 +19,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     Pads sequence lengths up to block multiples (padded kv keys sit at
     causal-masked positions > every real query, padded q rows are sliced
-    off).  Non-causal inputs are delegated to the reference path (the
-    kernel is causal-only by design).
+    off).  The kernel is causal-only: its padding relies on the mask.
     """
     if not causal:
-        from repro.kernels.flash_attention.ref import attention_ref
-        return attention_ref(q, k, v, causal=False, scale=scale)
+        raise NotImplementedError(
+            "flash_attention is causal-only; use the XLA attention path "
+            "for non-causal inputs")
     b, sq, hq, hd = q.shape
     sk = k.shape[1]
     block_q = min(block_q, max(16, 1 << (sq - 1).bit_length()))

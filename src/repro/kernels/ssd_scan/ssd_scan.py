@@ -21,8 +21,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
-                state_ref, *, chunk: int, n_chunks: int):
+def _ssd_kernel(x_ref, dt_ref, dtc_ref, cum_ref, cumc_ref, b_ref, c_ref,
+                y_ref, state_out_ref, state_ref, *, chunk: int,
+                n_chunks: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -30,36 +31,37 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)                       # [Q, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)                     # [1, Q]
-    a = a_ref[0]                                              # scalar
+    dt_row = dt_ref[0, 0]                                     # [1, Q]
+    dt_col = dtc_ref[0, 0]                                    # [Q, 1]
+    cum_row = cum_ref[0, 0]                                   # [1, Q]
+    cum_col = cumc_ref[0, 0]                                  # [Q, 1]
+    total = cum_col[chunk - 1:, :]                            # [1, 1]
     b = b_ref[0, 0].astype(jnp.float32)                      # [Q, N]
     c = c_ref[0, 0].astype(jnp.float32)                      # [Q, N]
 
-    adt = dt[0] * a                                           # [Q]
-    cum = jnp.cumsum(adt)                                     # [Q]
     # within-chunk decay L[q, k] = exp(cum_q - cum_k) for k <= q
-    diff = cum[:, None] - cum[None, :]
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.where(ki <= qi, jnp.exp(diff), 0.0)
+    lmat = jnp.where(ki <= qi, jnp.exp(cum_col - cum_row), 0.0)
     scores = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * lmat            # [Q, K]
-    scores = scores * dt[0][None, :]
+        preferred_element_type=jnp.float32) * lmat * dt_row  # [Q, K]
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # cross-chunk: y += exp(cum_q) * C_q . S_prev
     state = state_ref[...]                                    # [N, P]
     y_off = jax.lax.dot_general(
         c, state, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * jnp.exp(cum)[:, None]
+        preferred_element_type=jnp.float32) * jnp.exp(cum_col)
     y_ref[0, 0] = (y + y_off).astype(y_ref.dtype)
     # state update: S = exp(cum_Q) S + sum_k exp(cum_Q - cum_k) dt_k B_k x_k
-    w = jnp.exp(cum[-1] - cum) * dt[0]                        # [Q]
+    w = jnp.exp(total - cum_col) * dt_col                     # [Q, 1]
     s_new = jax.lax.dot_general(
-        b * w[:, None], x, (((0,), (0,)), ((), ())),
+        b * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                   # [N, P]
-    state_ref[...] = state * jnp.exp(cum[-1]) + s_new
+    # [1, 1] -> [N, 1] -> [N, P]: the TPU broadcasts one axis at a time
+    decay = jnp.exp(jnp.broadcast_to(total, (state.shape[0], 1)))
+    state_ref[...] = state * decay + s_new
 
     @pl.when(ci == n_chunks - 1)
     def _finish():
@@ -81,11 +83,18 @@ def ssd_pallas(x, dt, a, b, c, *, chunk: int = 128, initial_state=None,
     rep = h // g
 
     xt = jnp.transpose(x, (0, 2, 1, 3))                       # [B,H,L,P]
-    dtt = jnp.transpose(dt, (0, 2, 1))[:, :, None, :]         # [B,H,1,L]
+    dtt = jnp.transpose(dt, (0, 2, 1)).astype(jnp.float32)    # [B,H,L]
+    # the within-chunk cumulative decay is computed here, by the same
+    # cumsum as the reference (the TPU kernel language has none)
+    adt = dtt * a.astype(jnp.float32)[None, :, None]
+    cum = jnp.cumsum(adt.reshape(bsz, h, nc, chunk), axis=-1).reshape(
+        bsz, h, seqlen)
     bt = jnp.transpose(b, (0, 2, 1, 3))                       # [B,G,L,N]
     ct = jnp.transpose(c, (0, 2, 1, 3))
 
     grid = (bsz, h, nc)
+    row = pl.BlockSpec((1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, 0, ci))
+    col = pl.BlockSpec((1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0))
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc)
     y, state = pl.pallas_call(
         kernel,
@@ -93,9 +102,9 @@ def ssd_pallas(x, dt, a, b, c, *, chunk: int = 128, initial_state=None,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p),
                          lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, 1, chunk),
-                         lambda bi, hi, ci: (bi, hi, 0, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            # per-position scalars (dt, cum) arrive as a row and as a
+            # column, the two layouts the kernel broadcasts them in
+            row, col, row, col,
             pl.BlockSpec((1, 1, chunk, n),
                          lambda bi, hi, ci: (bi, hi // rep, ci, 0)),
             pl.BlockSpec((1, 1, chunk, n),
@@ -114,7 +123,8 @@ def ssd_pallas(x, dt, a, b, c, *, chunk: int = 128, initial_state=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xt, dtt, a.astype(jnp.float32), bt, ct)
+    )(xt, dtt[:, :, None, :], dtt[..., None], cum[:, :, None, :],
+      cum[..., None], bt, ct)
 
     y = jnp.transpose(y, (0, 2, 1, 3))                        # [B,L,H,P]
     state = jnp.transpose(state, (0, 1, 3, 2))                # [B,H,P,N]

@@ -217,6 +217,8 @@ def main():
                          "write a Chrome trace JSON here (open in "
                          "Perfetto)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.daemon:
         serve_daemon(DaemonServeRun(priority_hi=args.priority_hi,
                                     deadline_ms=args.deadline_ms,

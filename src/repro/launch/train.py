@@ -25,7 +25,7 @@ from repro.ckpt.checkpoint import CheckpointManager
 from repro.ckpt.fault import FaultInjector, InjectedFault, StepTimeout, \
     Watchdog, run_with_restarts
 from repro.data.pipeline import DataConfig, Pipeline
-from repro.launch import steps as steps_mod
+from repro.launch import mesh as mesh_mod, steps as steps_mod
 from repro.models import api
 from repro.optim import adamw, grad_compress as gc
 from repro.sharding import partition
@@ -52,7 +52,7 @@ class TrainRun:
 
 def _mesh_and_rules(elastic_phase: int = 0):
     n = jax.device_count()
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = mesh_mod.make_mesh((n, 1), ("data", "model"))
     # elastic phase 1 flips the FSDP rule — restoring across phases
     # exercises reshard-on-restore (the FOS replacement primitive)
     overrides = {"embed": None} if elastic_phase else None

@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import zlib
 from typing import Any, Callable
 
 import jax
@@ -363,7 +364,8 @@ def init_params(cfg: ModelConfig, key: jax.Array):
     leaves = []
     for path, spec in flat:
         pstr = "/".join(str(p) for p in path)
-        k = jax.random.fold_in(key, abs(hash(pstr)) % (2 ** 31))
+        # crc32, not hash(): str hashes change from process to process
+        k = jax.random.fold_in(key, zlib.crc32(pstr.encode()) % (2 ** 31))
         leaves.append(_init_leaf(spec, k, cfg))
     return jax.tree.unflatten(treedef, leaves)
 

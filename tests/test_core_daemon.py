@@ -119,3 +119,188 @@ def test_registry_roundtrip(tmp_path):
     m = reg2.module("mandelbrot")
     assert m.footprints == [1, 2, 4]
     assert m.load_builder() is zoo.build_mandelbrot
+
+
+def test_builder_args_travel_with_the_descriptor(tmp_path):
+    """A descriptor's `meta["builder_args"]` reaches its builder, and
+    survives a registry save/load (it is how a reduced `lm-forward` is
+    registered)."""
+    from repro.core import lm_forward_descriptor
+    reg = Registry()
+    reg.register_module(lm_forward_descriptor(reduced=True, seq=64))
+    reg.save(tmp_path)
+    desc = Registry.load(tmp_path).module("lm-forward")
+    builder = desc.load_builder()
+    assert builder.func is zoo.build_lm_forward
+    assert builder.keywords == {"reduced": True, "seq": 64}
+    # the default descriptor serves the full-width config
+    assert lm_forward_descriptor().load_builder() is zoo.build_lm_forward
+
+
+def test_lm_forward_through_daemon_matches_direct_forward():
+    """`lm-forward` served by the daemon (reduced granite, CPU size) gives
+    the logits of the same weights run through `stack.forward` directly."""
+    from repro.configs import granite_3_8b
+    from repro.core import lm_forward_descriptor
+    from repro.models import api, stack
+    cfg = granite_3_8b.REDUCED
+    spec = uniform_shell("host1_s1", (1, 1), 1)
+    reg = default_registry()
+    reg.register_module(lm_forward_descriptor(reduced=True, seq=64))
+    rng = np.random.default_rng(0)
+    chunks = [(rng.integers(0, cfg.vocab, (8, 64)).astype(np.int32),)
+              for _ in range(2)]
+    d = Daemon(Shell(spec), reg)
+    try:
+        outs = d.submit("carol", "lm-forward", chunks,
+                        priority=3).future.result(timeout=300)
+        placed = d.metrics["modules"]["lm-forward"]
+    finally:
+        d.shutdown()
+    assert placed["placements"] == 1 and placed["compile_s"] > 0
+    params = api.init_params(cfg, jax.random.PRNGKey(0))
+
+    @jax.jit
+    def forward(p, tokens):
+        h, _ = stack.forward(p, cfg, {"tokens": tokens})
+        return stack.unembed(p, cfg, h[:, -1:])[:, 0]
+
+    for out, (tokens,) in zip(outs, chunks):
+        assert out.shape == (8, cfg.padded_vocab)
+        np.testing.assert_allclose(np.asarray(out)[:, :cfg.vocab],
+                                   np.asarray(forward(params, tokens))
+                                   [:, :cfg.vocab], rtol=1e-6, atol=1e-6)
+
+
+def test_placement_builds_weights_on_the_slot():
+    """Placing a module leaves one copy of its weights, made on the
+    slot's devices in the slot's sharding (no host-side copy kept)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.module import AccelModule
+    shell = Shell(uniform_shell("host1_s1", (1, 1), 1))
+    slot = shell.slots[0]
+    mod = AccelModule("matmul", zoo.build_matmul, [1])
+    before = jax.live_arrays()          # held, so no id is reused
+    pl = mod.place(slot, 1)
+    seen = {id(a) for a in before}
+    new = [a for a in jax.live_arrays() if id(a) not in seen]
+    leaves = jax.tree.leaves(pl.weights_on_slot)
+    assert sorted(id(a) for a in new) == sorted(id(a) for a in leaves)
+    assert pl.weights_on_slot["a"].sharding == \
+        NamedSharding(slot.mesh, P(None, None))
+
+
+@pytest.mark.parametrize("env_dir", [None, "cache-from-env"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """The entry points' compile cache: `JAX_COMPILATION_CACHE_DIR` when
+    set, else the fixed directory in the checkout.  Run in a child so this
+    process's JAX config stays as it is."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch import compile_cache
+    src = compile_cache.CACHE_DIR.parent / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = str(compile_cache.CACHE_DIR)
+    if env_dir is not None:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "print(enable_compile_cache())\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
+
+
+_CACHE_CODE = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+from repro.core import Shell, uniform_shell, zoo
+from repro.core.module import AccelModule
+
+cache = os.environ["JAX_COMPILATION_CACHE_DIR"]
+def entries():
+    return set(os.listdir(cache)) if os.path.isdir(cache) else set()
+
+shell = Shell(uniform_shell("host2_s2", (1, 2), 2))
+AccelModule("matmul", zoo.build_matmul, [1]).place(shell.slots[0], 1)
+one = entries()
+AccelModule("matmul", zoo.build_matmul, [2]).place(
+    shell.merged_slot([0, 1]), 2)
+two = entries()
+AccelModule("sobel", zoo.build_sobel, [1]).place(shell.slots[1], 1)
+again = entries()
+print(len(one), len(two - one), len(again - two),
+      jax.config.jax_enable_compilation_cache)
+"""
+
+
+def test_multi_device_placement_stays_out_of_persistent_cache(tmp_path):
+    """A placement on a slot of one device reads and writes the persistent
+    compilation cache; one spanning two devices neither reads nor writes
+    it, and the cache is on again after it.  Two host devices, in a
+    child, so this process keeps its one-device view."""
+    import os
+    import subprocess
+    import sys
+    from repro.launch import compile_cache
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(compile_cache.CACHE_DIR.parent / "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    out = subprocess.run([sys.executable, "-c", _CACHE_CODE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    n_one, n_two, n_again, enabled = out.stdout.split()
+    assert int(n_one) > 0, "one-device placement wrote no cache entry"
+    assert int(n_two) == 0, "two-device placement wrote to the cache"
+    assert int(n_again) > 0, "the cache stayed off after the placement"
+    assert enabled == "True"
+
+
+def test_preempted_placement_never_overlaps_its_successor(monkeypatch):
+    """A preempted assignment still placing on its slot finishes before
+    the preemptor's placement starts there: two programs never run on the
+    same chips at once."""
+    import threading
+    import time
+    from repro.core import PolicyConfig
+    from repro.core.module import AccelModule
+    place = AccelModule.place
+    started = threading.Event()
+    lock = threading.Lock()
+    on_slot = {"now": 0, "most": 0}
+
+    def slow_place(self, slot, footprint):
+        with lock:
+            on_slot["now"] += 1
+            on_slot["most"] = max(on_slot["most"], on_slot["now"])
+        started.set()
+        try:
+            time.sleep(0.5)
+            return place(self, slot, footprint)
+        finally:
+            with lock:
+                on_slot["now"] -= 1
+
+    monkeypatch.setattr(AccelModule, "place", slow_place)
+    spec = uniform_shell("host1_s1", (1, 1), 1)
+    d = Daemon(Shell(spec), default_registry(),
+               PolicyConfig(preemptive=True))
+    try:
+        re, im = _mandel_inputs(seed=3)
+        img = np.random.default_rng(4).random((1024, 1024)) \
+            .astype(np.float32)
+        lo = d.submit("lo", "mandelbrot", [(re, im)], priority=0)
+        assert started.wait(timeout=60)
+        hi = d.submit("hi", "sobel", [(img,)], priority=5)
+        assert len(hi.future.result(timeout=300)) == 1
+        assert len(lo.future.result(timeout=300)) == 1
+    finally:
+        d.shutdown()
+    assert d.stats["preemptions"] >= 1
+    assert on_slot["most"] == 1

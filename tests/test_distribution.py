@@ -20,6 +20,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro import configs
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models import api, io, stack
 from repro.optim import adamw
 from repro.sharding import partition
@@ -53,7 +54,7 @@ for arch in ["llama3.2-3b", "qwen3-moe-30b-a3b", "mamba2-780m",
     ref_loss = stack.build_loss_fn(ref_cfg)(params, batch)
 
     # sharded: 2x4 mesh, train rules
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = partition.make_rules("train")
     loss_fn = stack.build_loss_fn(cfg, mesh, batch_axes=rules.batch_axes)
     state_sh = partition.tree_shardings(api.param_specs(cfg), mesh, rules)

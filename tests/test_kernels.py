@@ -158,3 +158,12 @@ def test_ssd_ref_matches_naive_recurrence():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s_ref), np.asarray(s),
                                atol=1e-4, rtol=1e-4)
+
+
+def test_flash_attention_refuses_non_causal():
+    """The kernel is causal-only; a non-causal call raises instead of
+    silently running another implementation."""
+    q, k, v = _qkv(jax.random.PRNGKey(5), 1, 128, 128, 2, 2, 64,
+                   jnp.float32)
+    with pytest.raises(NotImplementedError):
+        fa_ops.flash_attention(q, k, v, causal=False, interpret=True)
