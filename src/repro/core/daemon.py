@@ -32,6 +32,18 @@ in-process XLA computation cannot restore partial context, so a resumed
 chunk re-runs in full (a real FPGA backend would read back and restore
 the PR region state); the scheduler's decisions and accounting are
 checkpoint-aware either way.
+
+Tracing: the served path opens `jax.profiler.TraceAnnotation` spans named
+`fos.<step>` (`module.span`), on the profiler's clock beside the device's
+operations: `fos.schedule` around each scheduling pass, and per chunk
+`fos.chunk` around the worker's whole run, holding `fos.slot_wait`,
+`fos.place` (only when it compiles or builds weights), `fos.adapt`,
+`fos.put`, `fos.dispatch`, `fos.wait` and `fos.complete`.  A chunk's spans
+carry its job id (`gid`), `chunk`, `aid` and `tenant`.  `Daemon.stats`
+counts, in ns, what the spans time: each job's queue time (submit to the
+pass that issues its first chunk) and, over every chunk run on a slot
+(`runs` = `chunks` + `discarded`), the wait for the slot, the adaptation,
+the run, and the runs thrown away after a preemption.
 """
 from __future__ import annotations
 
@@ -47,7 +59,8 @@ import jax
 
 from repro.core import bus
 from repro.core.fabric import Fabric
-from repro.core.module import AccelModule, Placement, run_placement
+from repro.core.module import AccelModule, Placement, chunk_tags, \
+    run_placement, span
 from repro.core.registry import Registry
 from repro.core.scheduler import Assignment, PolicyConfig, SchedulerState
 from repro.core.shell import Shell
@@ -117,8 +130,14 @@ class Daemon:
         self._pool = ThreadPoolExecutor(max_workers=max_workers)
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True)
+        # counters, ns and counts (see the module's docstring); jobs not
+        # yet issued a chunk wait in `_submitted_ns` (gid -> submit ns)
         self.stats = {"reconfigurations": 0, "reuses": 0, "chunks": 0,
-                      "preemptions": 0, "sched_ns": 0, "sched_calls": 0}
+                      "preemptions": 0, "sched_ns": 0, "sched_calls": 0,
+                      "queue_ns": 0, "queue_jobs": 0, "runs": 0,
+                      "slot_wait_ns": 0, "adapt_ns": 0, "run_ns": 0,
+                      "discarded": 0, "discarded_ns": 0}
+        self._submitted_ns: dict[int, int] = {}
         # per module: placements installed, and their compile and
         # weight-init seconds summed
         self.module_stats: dict[str, dict] = {}
@@ -140,7 +159,8 @@ class Daemon:
         scheduler lock so every block is from the same instant:
 
         - ``daemon``: executor counters (reconfigurations, reuses,
-          chunks, preemptions, scheduling-pass timing);
+          chunks, preemptions, scheduling-pass timing, queue, slot-wait,
+          adaptation and run times, discarded runs);
         - ``modules``: per module, placements installed, the seconds
           compiling their programs (``compile_s``) and compiling and
           running their on-slot weight inits (``init_s``);
@@ -246,6 +266,7 @@ class Daemon:
                 return h
             self._results[job.gid] = [None] * job.n_chunks
             self._handles[job.gid] = h
+            self._submitted_ns[job.gid] = time.perf_counter_ns()
         self._events.put(("submit", None))
         return h
 
@@ -290,7 +311,8 @@ class Daemon:
         shell = self.shells[shell_name]
         slot = (shell.slots[a.rng.start] if a.rng.size == 1 else
                 shell.merged_slot(list(a.rng.slots)))
-        pl = mod.place(slot, a.footprint)
+        with span("place"):
+            pl = mod.place(slot, a.footprint)
         with self._lock:
             # a preempted victim mid-dispatch must not clobber the
             # placement its preemptor just installed on the same range
@@ -319,7 +341,7 @@ class Daemon:
                     self._events.get_nowait()
             except queue.Empty:
                 pass
-            with self._lock:
+            with self._lock, span("schedule"):
                 t0 = time.perf_counter_ns()
                 if self.fabric.network.active:
                     # mirror the simulator's "net" release events on
@@ -338,10 +360,29 @@ class Daemon:
                 # daemon under heavy stealing
                 self.fabric.drain_moved()
                 self._handle_preempted_locked()
+                t_issued = time.perf_counter_ns()
+                issued = [(shell_name, a,
+                           self._issue_locked(shell_name, a, t_issued))
+                          for shell_name, a in assignments]
                 self.stats["sched_ns"] += time.perf_counter_ns() - t0
                 self.stats["sched_calls"] += 1
-            for shell_name, a in assignments:
-                self._pool.submit(self._run_assignment, shell_name, a)
+            for shell_name, a, tags in issued:
+                self._pool.submit(self._run_assignment, shell_name, a, tags,
+                                  t_issued)
+
+    def _issue_locked(self, shell_name: str, a: Assignment,
+                      now_ns: int) -> dict:
+        """Count the queue time of a job whose first chunk this pass
+        issues; returns the chunk's span tags."""
+        entry = self.fabric.sub(shell_name, a.rid)
+        gid, chunk = ((entry[0].gid, entry[1][a.chunk]) if entry is not None
+                      else (a.rid, a.chunk))
+        t_submit = self._submitted_ns.pop(gid, None)
+        if t_submit is not None:
+            self.stats["queue_ns"] += now_ns - t_submit
+            self.stats["queue_jobs"] += 1
+        tenant = self.fabric.states[shell_name].requests[a.rid].tenant
+        return {"gid": gid, "chunk": chunk, "aid": a.aid, "tenant": tenant}
 
     def _gid_of_locked(self, shell_name: str, rid: int) -> int:
         """Job id for a sub-request; requests created directly on a shell
@@ -364,6 +405,7 @@ class Daemon:
             if not self.fabric.finished(gid):
                 return
             self._handles.pop(gid, None)
+            self._submitted_ns.pop(gid, None)
             self._results.pop(gid, None)
             # keep the job/request records (stats/queries) but release
             # the input arrays — a long-running daemon must not
@@ -382,13 +424,18 @@ class Daemon:
                     req.payloads = None
                 return
 
-    def _run_assignment(self, shell_name: str, a: Assignment):
-        with contextlib.ExitStack() as held:
-            for i in sorted(a.rng.slots):        # one order: no deadlock
-                held.enter_context(self._slot_locks[(shell_name, i)])
-            self._run_on_slots(shell_name, a)
+    def _run_assignment(self, shell_name: str, a: Assignment, tags: dict,
+                        t_issued: int):
+        with chunk_tags(tags), span("chunk"), \
+                contextlib.ExitStack() as held:
+            with span("slot_wait"):
+                for i in sorted(a.rng.slots):    # one order: no deadlock
+                    held.enter_context(self._slot_locks[(shell_name, i)])
+            self._run_on_slots(shell_name, a,
+                               time.perf_counter_ns() - t_issued)
 
-    def _run_on_slots(self, shell_name: str, a: Assignment):
+    def _run_on_slots(self, shell_name: str, a: Assignment,
+                      slot_wait_ns: int):
         with self._lock:
             if a.aid in self._cancelled:   # preempted before we started
                 self._cancelled.discard(a.aid)
@@ -396,26 +443,36 @@ class Daemon:
                 self._events.put(("cancelled", None))
                 return
         st = self.fabric.states[shell_name]
+        adapt_ns = run_ns = 0
         try:
             pl = self._placement(shell_name, a)
             req = st.requests[a.rid]
             payload = req.payloads[a.chunk]
             prog = pl.module.program(pl.slot, pl.footprint)
-            args, _ = bus.adapt_inputs(
-                payload if isinstance(payload, tuple) else (payload,),
-                prog.abstract_inputs)
-            t_run = _now_ms()
+            t0 = time.perf_counter_ns()
+            with span("adapt"):
+                args, _ = bus.adapt_inputs(
+                    payload if isinstance(payload, tuple) else (payload,),
+                    prog.abstract_inputs)
+            t1 = time.perf_counter_ns()
+            adapt_ns = t1 - t0
             out = run_placement(pl, *args)
-            t_run = _now_ms() - t_run
+            run_ns = time.perf_counter_ns() - t1
             err = None
         except Exception as e:  # noqa: BLE001 - propagate to the future
-            out, err, t_run = None, e, 0.0
-        with self._lock:
+            out, err = None, e
+        with span("complete"), self._lock:
             self._cancelled.discard(a.aid)
+            self.stats["runs"] += 1
+            self.stats["slot_wait_ns"] += slot_wait_ns
+            self.stats["adapt_ns"] += adapt_ns
+            self.stats["run_ns"] += run_ns
             entry = self.fabric.sub(shell_name, a.rid)
             if not self.fabric.complete(shell_name, a, now=_now_ms()):
                 # preempted mid-dispatch: discard the partial result; the
                 # chunk was requeued and re-runs under a fresh assignment
+                self.stats["discarded"] += 1
+                self.stats["discarded_ns"] += run_ns
                 self._finalize_locked(self._gid_of_locked(shell_name, a.rid))
                 self._events.put(("discarded", None))
                 return
@@ -423,15 +480,15 @@ class Daemon:
             if err is None and self.policy.refine_cost_model:
                 # reconfigured chunks refine too — an always-
                 # reconfiguring module must not keep a stale estimate
-                # forever.  t_run wraps run_placement only, so unlike
+                # forever.  run_ns wraps run_placement only, so unlike
                 # the simulator's elapsed time it never contains the
                 # reconfiguration cost (placement/compile happen before
                 # the clock starts) and nothing is subtracted here.
                 # Resumed chunks (a.frac < 1) re-run in full in-process,
-                # so t_run is already a full-chunk observation — no
+                # so run_ns is already a full-chunk observation — no
                 # frac scaling either (unlike the simulator).
                 self.fabric.cost.observe(a.module, a.footprint,
-                                         max(1e-3, t_run),
+                                         max(1e-3, run_ns / 1e6),
                                          self.fabric.speeds[shell_name])
             if entry is not None:
                 job, cmap = entry

@@ -17,6 +17,7 @@ a ModuleProgram describing fn / abstract inputs / shardings / weights.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 import time
 from typing import Any, Callable
@@ -56,7 +57,6 @@ class Placement:
     weights_on_slot: Any
     init_time_s: float       # compile and run the on-slot weight init
     compile_time_s: float    # compile the module's program alone
-    cache_hit: bool
 
 
 class AccelModule:
@@ -68,8 +68,6 @@ class AccelModule:
         self.builder = builder
         self.footprints = list(footprints)
         self._programs: dict[tuple, ModuleProgram] = {}
-        self._compile_count = 0
-        self._compile_keys: set[tuple] = set()
         self.weights_key = weights_key
 
     # -- decoupled compilation -------------------------------------------------
@@ -105,24 +103,50 @@ class AccelModule:
                     else contextlib.nullcontext())
         with uncached:
             t0 = time.perf_counter()
-            executable = jax.jit(prog.fn, in_shardings=shardings) \
+            executable = jax.jit(_named(prog.fn, self.name),
+                                 in_shardings=shardings) \
                 .lower(*args).compile()
             t1 = time.perf_counter()
             # the weights are generated on the slot's devices in their
             # sharding, never staged elsewhere
             w_dev = (jax.block_until_ready(
-                jax.jit(prog.init_weights, out_shardings=w_sh)(key))
+                jax.jit(_named(prog.init_weights, f"{self.name}.init"),
+                        out_shardings=w_sh)(key))
                 if prog.init_weights is not None else None)
             t2 = time.perf_counter()
-        # congruence-class cache bookkeeping: a repeat compile of the same
-        # (program, congruence) is a relocation, not a fresh compile
-        ckey = (slot.congruence_key, footprint)
-        cache_hit = ckey in self._compile_keys
-        self._compile_keys.add(ckey)
-        self._compile_count += 1
         return Placement(self, footprint, slot, executable, w_dev,
-                         init_time_s=t2 - t1, compile_time_s=t1 - t0,
-                         cache_hit=cache_hit)
+                         init_time_s=t2 - t1, compile_time_s=t1 - t0)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """`fn` under `name`, which `jax.jit` gives its compiled program
+    (`jit_<name>`), so the device trace tells modules' programs apart."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
+# the tags of the chunk the current thread serves, carried by every
+# `fos.*` span it opens (set by `chunk_tags`; empty outside a chunk)
+_TAGS: contextvars.ContextVar[dict] = contextvars.ContextVar("fos_tags",
+                                                             default={})
+
+
+def span(step: str) -> jax.profiler.TraceAnnotation:
+    """The profiler span `fos.<step>`, tagged with the current chunk.  It
+    costs little when no trace is being taken."""
+    return jax.profiler.TraceAnnotation(f"fos.{step}", **_TAGS.get())
+
+
+@contextlib.contextmanager
+def chunk_tags(tags: dict):
+    """Tag the `fos.*` spans this thread opens inside the block."""
+    token = _TAGS.set(tags)
+    try:
+        yield
+    finally:
+        _TAGS.reset(token)
 
 
 def run_placement(placement: Placement, *chunk_args):
@@ -132,8 +156,11 @@ def run_placement(placement: Placement, *chunk_args):
     prog = placement.module.program(placement.slot, placement.footprint)
     mesh = placement.slot.mesh
     args = []
-    for a, ps in zip(chunk_args, prog.input_pspecs):
-        sh = jax.tree.map(lambda p: NamedSharding(mesh, p), ps)
-        args.append(jax.device_put(a, sh))
-    out = placement.executable(placement.weights_on_slot, *args)
-    return jax.block_until_ready(out)
+    with span("put"):
+        for a, ps in zip(chunk_args, prog.input_pspecs):
+            sh = jax.tree.map(lambda p: NamedSharding(mesh, p), ps)
+            args.append(jax.device_put(a, sh))
+    with span("dispatch"):
+        out = placement.executable(placement.weights_on_slot, *args)
+    with span("wait"):
+        return jax.block_until_ready(out)

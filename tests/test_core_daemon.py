@@ -190,6 +190,17 @@ def test_placement_builds_weights_on_the_slot():
         NamedSharding(slot.mesh, P(None, None))
 
 
+@pytest.mark.parametrize("module,builder", [("matmul", "build_matmul"),
+                                            ("sobel", "build_sobel")])
+def test_placed_program_is_named_after_its_module(module, builder):
+    """Each module's compiled program carries the module's name, so the
+    device trace's `XLA Modules` line tells modules apart."""
+    from repro.core.module import AccelModule
+    slot = Shell(uniform_shell("host1_s1", (1, 1), 1)).slots[0]
+    pl = AccelModule(module, getattr(zoo, builder), [1]).place(slot, 1)
+    assert pl.executable.as_text().startswith(f"HloModule jit_{module},")
+
+
 @pytest.mark.parametrize("env_dir", [None, "cache-from-env"])
 def test_compile_cache_dir(tmp_path, env_dir):
     """The entry points' compile cache: `JAX_COMPILATION_CACHE_DIR` when
@@ -304,3 +315,118 @@ def test_preempted_placement_never_overlaps_its_successor(monkeypatch):
         d.shutdown()
     assert d.stats["preemptions"] >= 1
     assert on_slot["most"] == 1
+
+
+def _one_slot_daemon(**policy):
+    from repro.core import PolicyConfig
+    spec = uniform_shell("host1_s1", (1, 1), 1)
+    return Daemon(Shell(spec), default_registry(), PolicyConfig(**policy))
+
+
+def test_stats_counters_reconcile():
+    """Every chunk run on the slot is counted once, completed or thrown
+    away; every job's queue time is counted once; the timers advance.
+    Under preemption, with threads switching often."""
+    import sys
+    d = _one_slot_daemon(preemptive=True)
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        re, im = _mandel_inputs(seed=5)
+        img = np.random.default_rng(6).random((1024, 1024)) \
+            .astype(np.float32)
+        hs = []
+        for i in range(4):
+            hs.append(d.submit("alice", "mandelbrot", [(re, im)] * 2))
+            hs.append(d.submit("bob", "sobel", [(img,)], priority=3))
+        for h in hs:
+            h.future.result(timeout=300)
+    finally:
+        sys.setswitchinterval(switch)
+        d.shutdown()
+    s = d.stats
+    assert s["chunks"] == 12
+    assert s["runs"] == s["chunks"] + s["discarded"]
+    assert s["discarded_ns"] <= s["run_ns"]
+    assert s["queue_jobs"] == len(hs)
+    for key in ("sched_ns", "queue_ns", "slot_wait_ns", "adapt_ns",
+                "run_ns"):
+        assert s[key] > 0, key
+
+
+def test_preempted_run_is_counted_as_discarded(monkeypatch):
+    """A chunk preempted while it places still runs to its end; its result
+    is thrown away and counted once in `discarded`, and its preemptor
+    waited for the slot."""
+    import threading
+    import time
+    from repro.core.module import AccelModule
+    place = AccelModule.place
+    started = threading.Event()
+
+    def slow_place(self, slot, footprint):
+        started.set()
+        time.sleep(0.5)
+        return place(self, slot, footprint)
+
+    monkeypatch.setattr(AccelModule, "place", slow_place)
+    # no aging: the requeued victim cannot come back to preempt `hi`
+    d = _one_slot_daemon(preemptive=True, starvation_bound_ms=1e9)
+    try:
+        re, im = _mandel_inputs(seed=3)
+        img = np.random.default_rng(4).random((1024, 1024)) \
+            .astype(np.float32)
+        lo = d.submit("lo", "mandelbrot", [(re, im)], priority=0)
+        assert started.wait(timeout=60)
+        hi = d.submit("hi", "sobel", [(img,)], priority=5)
+        assert len(hi.future.result(timeout=300)) == 1
+        assert len(lo.future.result(timeout=300)) == 1
+    finally:
+        d.shutdown()
+    s = d.stats
+    assert s["preemptions"] == 1
+    assert s["discarded"] == 1 and s["discarded_ns"] > 0
+    assert s["discarded_ns"] <= s["run_ns"]
+    assert s["runs"] == s["chunks"] + s["discarded"] == 3
+    assert s["slot_wait_ns"] > 0
+    assert s["queue_jobs"] == 2
+
+
+def test_chunk_spans_nest_on_the_profiler_clock(tmp_path):
+    """A profiler trace of one job holds its `fos.chunk` span with the
+    slot wait, adaptation, copy, dispatch, wait and completion nested
+    inside it in that order, each tagged with the job's id."""
+    d = _one_slot_daemon()
+    re, im = _mandel_inputs(seed=7)
+    try:
+        d.submit("alice", "mandelbrot", [(re, im)]).future.result(
+            timeout=300)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            h = d.submit("alice", "mandelbrot", [(re, im)])
+            h.future.result(timeout=300)
+        finally:
+            d.shutdown()          # the worker leaves its spans first
+            jax.profiler.stop_trace()
+    finally:
+        d.shutdown()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+            dict(ev.stats))
+           for plane in data.planes if plane.name.startswith("/host:")
+           for line in plane.lines for ev in line.events
+           if ev.name.startswith("fos.")]
+    assert any(n == "fos.schedule" for n, *_ in evs)
+    mine = sorted((e for e in evs if e[3].get("gid") == h.rid),
+                  key=lambda e: (e[1], -e[2]))
+    chunk = mine[0]
+    assert chunk[0] == "fos.chunk"
+    assert chunk[3] == {"gid": h.rid, "chunk": 0, "aid": chunk[3]["aid"],
+                        "tenant": "alice"}
+    assert [e[0] for e in mine[1:]] == [
+        "fos.slot_wait", "fos.adapt", "fos.put", "fos.dispatch", "fos.wait",
+        "fos.complete"]
+    for name, s, e, tags in mine[1:]:
+        assert chunk[1] <= s <= e <= chunk[2], name
+        assert tags == chunk[3], name
