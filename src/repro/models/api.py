@@ -82,7 +82,7 @@ class ModelConfig:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
     kv_dtype: Any = jnp.bfloat16
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"         # auto | xla | pallas | pallas_interpret
     ssd_impl: str = "xla"
     remat: str = "none"             # none | full | dots
     loss_chunk: int = 0             # 0 = unchunked final projection

@@ -357,10 +357,64 @@ def sharded_cache_update_attention(q, k_new, v_new, k_cache, v_cache,
     )(q, k_new, v_new, k_cache, v_cache)
 
 
+def flash_serves(platform: str, *, causal: bool, cached: bool,
+                 cross: bool, attn_chunk: int, mesh_devices: int,
+                 head_dim: int, seq: int) -> bool:
+    """Whether the default attention (`attn_impl="auto"`) runs on the
+    Pallas flash kernel, from what the program can observe when it is
+    traced and lowered: causal self-attention without a KV cache, not
+    chunked, on one device, at a head_dim and sequence length that suit
+    the kernel's 128-lane tiling, lowered for a TPU.  Everything else
+    takes the XLA path."""
+    return (platform == "tpu" and causal and not cached and not cross
+            and not attn_chunk and mesh_devices == 1
+            and head_dim % 128 == 0 and seq % 128 == 0)
+
+
+def _mesh_devices(x, mesh) -> int:
+    """Devices the traced program spans: the mesh passed in, or the
+    abstract mesh of the traced array's sharding (set by `jit` from its
+    arguments' shardings)."""
+    traced = jax.typeof(x).sharding.mesh
+    return max(mesh.size if mesh is not None else 1,
+               traced.size if traced.axis_names else 1)
+
+
+def _self_attention(q, k, v, spec: AttentionSpec, attn_impl: str, mesh):
+    """Self-attention without a cache over fresh q, k, v."""
+    s = q.shape[1]
+
+    def flash(q, k, v, interpret=False):
+        from repro.kernels.flash_attention import ops as fa_ops
+        return fa_ops.flash_attention(q, k, v, causal=True, scale=spec.scale,
+                                      interpret=interpret)
+
+    def xla(q, k, v):
+        if spec.attn_chunk and s > spec.attn_chunk:
+            return _chunked_sdpa(q, k, v, spec, 0, causal=spec.causal)
+        mask = causal_mask(s, s) if spec.causal else None
+        return _sdpa(q, k, v, spec, mask)
+
+    if attn_impl in ("pallas", "pallas_interpret") and spec.causal:
+        return flash(q, k, v, interpret=(attn_impl == "pallas_interpret"))
+    if attn_impl == "auto" and flash_serves(
+            "tpu", causal=spec.causal, cached=False, cross=False,
+            attn_chunk=spec.attn_chunk, mesh_devices=_mesh_devices(q, mesh),
+            head_dim=spec.head_dim, seq=s):
+        # the branch is chosen where the program is lowered: the kernel
+        # for a TPU, the XLA path for any other platform
+        return jax.lax.platform_dependent(q, k, v, tpu=flash, default=xla)
+    return xla(q, k, v)
+
+
 def attention(params, x, spec: AttentionSpec, positions,
-              attn_impl: str = "xla", kv_cache=None, cache_pos=None,
+              attn_impl: str = "auto", kv_cache=None, cache_pos=None,
               cross_kv=None, mesh=None):
     """General attention entry point; returns (out [B,S,D], new_cache|None).
+
+    attn_impl: "auto" (the Pallas flash kernel where `flash_serves`, else
+    XLA), "xla", "pallas" or "pallas_interpret" (the kernels for causal
+    self-attention and single-token decode, XLA elsewhere).
 
     - train / full self-attention: kv_cache is None.
     - prefill: kv_cache given, s > 1 -> attention over fresh k/v + cache fill.
@@ -387,16 +441,7 @@ def attention(params, x, spec: AttentionSpec, positions,
         new_cache = None
     elif kv_cache is None:
         q, k, v = _project_qkv(params, x, spec, positions)
-        if attn_impl in ("pallas", "pallas_interpret") and spec.causal:
-            from repro.kernels.flash_attention import ops as fa_ops
-            out = fa_ops.flash_attention(
-                q, k, v, causal=True, scale=spec.scale,
-                interpret=(attn_impl == "pallas_interpret"))
-        elif spec.attn_chunk and s > spec.attn_chunk:
-            out = _chunked_sdpa(q, k, v, spec, 0, causal=spec.causal)
-        else:
-            mask = causal_mask(s, s) if spec.causal else None
-            out = _sdpa(q, k, v, spec, mask)
+        out = _self_attention(q, k, v, spec, attn_impl, mesh)
         new_cache = None
     else:
         q, k, v = _project_qkv(params, x, spec, positions)
@@ -404,7 +449,7 @@ def attention(params, x, spec: AttentionSpec, positions,
                        and _flat_axes(rules.get("seq_kv")))
         if s == 1 and seq_sharded and \
                 kv_cache["k"].shape[1] % _n_seq_shards(mesh, rules) == 0 \
-                and attn_impl == "xla":
+                and attn_impl in ("xla", "auto"):
             out, k_cache, v_cache = sharded_cache_update_attention(
                 q, k, v, kv_cache["k"], kv_cache["v"], spec, cache_pos,
                 mesh, rules)

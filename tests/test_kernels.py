@@ -33,22 +33,27 @@ def _qkv(key, b, sq, sk, hq, hkv, hd, dtype):
 
 
 FLASH_CASES = [
-    # (b, sq, sk, hq, hkv, hd, dtype, block_q, block_k)
-    (1, 128, 128, 4, 4, 64, jnp.float32, 64, 64),      # MHA
-    (2, 256, 256, 8, 2, 64, jnp.float32, 128, 128),    # GQA 4:1
-    (1, 384, 384, 4, 1, 32, jnp.float32, 128, 128),    # MQA, non-pow2 seq
-    (1, 200, 200, 4, 2, 64, jnp.float32, 64, 64),      # ragged -> padding
-    (2, 128, 128, 4, 4, 128, jnp.bfloat16, 64, 64),    # bf16
-    (1, 512, 512, 2, 2, 16, jnp.float32, 128, 256),    # tiny head_dim
+    # (b, s, hq, hkv, hd, dtype, block); block None: picked from the shape
+    (1, 128, 4, 4, 64, jnp.float32, 64),               # MHA
+    (2, 256, 8, 2, 64, jnp.float32, 128),              # GQA 4:1
+    (1, 384, 4, 1, 32, jnp.float32, 128),              # MQA, non-pow2 seq
+    (1, 200, 4, 2, 64, jnp.float32, 64),               # ragged -> padding
+    (2, 128, 4, 4, 128, jnp.bfloat16, 64),             # bf16
+    (1, 512, 2, 2, 16, jnp.float32, 256),              # tiny head_dim
+    # Qwen3-14B's head layout (g = 5, hd 128) at the served S 512 and at
+    # 1024 (steps below the diagonal), batch and heads cut; one small
+    # unaligned case
+    (1, 512, 10, 2, 128, jnp.bfloat16, None),
+    (1, 1024, 5, 1, 128, jnp.bfloat16, None),
+    (2, 72, 6, 2, 48, jnp.float32, None),
 ]
 
 
-@pytest.mark.parametrize(
-    "b,sq,sk,hq,hkv,hd,dtype,bq,bk", FLASH_CASES)
-def test_flash_attention_matches_ref(b, sq, sk, hq, hkv, hd, dtype, bq, bk):
-    q, k, v = _qkv(jax.random.PRNGKey(0), b, sq, sk, hq, hkv, hd, dtype)
-    got = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
-                                 block_k=bk, interpret=True)
+@pytest.mark.parametrize("b,s,hq,hkv,hd,dtype,block", FLASH_CASES)
+def test_flash_attention_matches_ref(b, s, hq, hkv, hd, dtype, block):
+    q, k, v = _qkv(jax.random.PRNGKey(0), b, s, s, hq, hkv, hd, dtype)
+    got = fa_ops.flash_attention(q, k, v, causal=True, block=block,
+                                 interpret=True)
     want = fa_ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
@@ -158,6 +163,80 @@ def test_ssd_ref_matches_naive_recurrence():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s_ref), np.asarray(s),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_gradient_is_the_xla_paths(dtype):
+    """The kernel's custom VJP (the reference's, recomputed) against
+    jax.grad of the model's XLA attention."""
+    from repro.models import layers
+    b, s, hq, hkv, hd = 1, 128, 4, 2, 128
+    q, k, v = _qkv(jax.random.PRNGKey(6), b, s, s, hq, hkv, hd, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(7), (b, s, hq, hd))
+    spec = layers.AttentionSpec(n_heads=hq, n_kv_heads=hkv, head_dim=hd)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32) * w)
+
+    got = jax.grad(loss(lambda q, k, v: fa_ops.flash_attention(
+        q, k, v, interpret=True)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: layers._sdpa(
+        q, k, v, spec, layers.causal_mask(s, s))), argnums=(0, 1, 2))(q, k, v)
+    tol = dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 \
+        else dict(atol=1e-4, rtol=1e-4)
+    for g, r in zip(got, want):
+        assert g.dtype == r.dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(r, np.float32), **tol)
+
+
+FLASH_RULE = dict(causal=True, cached=False, cross=False, attn_chunk=0,
+                  mesh_devices=1, head_dim=128, seq=512)
+
+
+@pytest.mark.parametrize("platform,change,serves", [
+    ("tpu", {}, True),                                # served lm-forward
+    ("tpu", {"seq": 1024}, True),
+    ("cpu", {}, False),
+    ("tpu", {"causal": False}, False),
+    ("tpu", {"cached": True}, False),                 # prefill, decode
+    ("tpu", {"cross": True}, False),
+    ("tpu", {"attn_chunk": 1024}, False),
+    ("tpu", {"mesh_devices": 4}, False),              # sharded mesh
+    ("tpu", {"head_dim": 64}, False),
+    ("tpu", {"seq": 500}, False),
+])
+def test_flash_dispatch_rule(platform, change, serves):
+    from repro.models import layers
+    assert layers.flash_serves(platform, **{**FLASH_RULE, **change}) \
+        is serves
+
+
+@pytest.mark.parametrize("seq", [128, 96])
+def test_default_attention_on_cpu_is_the_xla_path(seq):
+    """At a shape the kernel would take on a TPU (seq 128) and at one it
+    would not, the default attention computes on the CPU exactly what
+    the XLA path computes, forward and backward."""
+    from repro.models import layers
+    spec = layers.AttentionSpec(n_heads=4, n_kv_heads=2, head_dim=128)
+    keys = jax.random.split(jax.random.PRNGKey(8), 5)
+    d = 256
+    params = {n: 0.05 * jax.random.normal(kk, shape) for n, kk, shape in
+              zip(("wq", "wk", "wv", "wo"), keys,
+                  ((d, 512), (d, 256), (d, 256), (512, d)))}
+    x = jax.random.normal(keys[4], (2, seq, d))
+    pos = jnp.arange(seq)
+
+    def run(impl):
+        def loss(p):
+            y, _ = layers.attention(p, x, spec, pos, attn_impl=impl)
+            return jnp.sum(y * y)
+        return jax.jit(jax.value_and_grad(loss))(params)
+
+    got, want = run("auto"), run("xla")
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
 def test_flash_attention_refuses_non_causal():

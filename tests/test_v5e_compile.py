@@ -61,6 +61,37 @@ def test_flash_attention_granite_heads_s2048(one_chip):
     _assert_kernel(fa_ops.flash_attention.lower(q, kv, kv).compile())
 
 
+def test_flash_attention_qwen3_heads_s512(one_chip):
+    from repro.kernels.flash_attention import ops as fa_ops
+    b, s, hq, hkv, hd = 8, 512, 40, 8, 128        # Qwen3-14B heads, served
+    q = _sds((b, s, hq, hd), jnp.bfloat16, one_chip)
+    kv = _sds((b, s, hkv, hd), jnp.bfloat16, one_chip)
+    _assert_kernel(fa_ops.flash_attention.lower(q, kv, kv).compile())
+
+
+@pytest.mark.parametrize("devices,kernel", [(1, True), (2, False)])
+def test_default_attention_takes_the_kernel_on_one_chip(topo, devices,
+                                                        kernel):
+    """The default attn_impl serves the kernel in a program on one chip
+    and keeps the XLA path in one sharded over two."""
+    from repro.models import layers
+    mesh = jax.sharding.Mesh(np.array(topo.devices[:devices]).reshape(
+        devices, 1), ("data", "model"))
+    spec = layers.AttentionSpec(n_heads=8, n_kv_heads=2, head_dim=128)
+    d, rep = 512, NamedSharding(mesh, P())
+    params = {"wq": _sds((d, 1024), jnp.bfloat16, rep),
+              "wk": _sds((d, 256), jnp.bfloat16, rep),
+              "wv": _sds((d, 256), jnp.bfloat16, rep),
+              "wo": _sds((1024, d), jnp.bfloat16, rep)}
+    x = _sds((4, 512, d), jnp.bfloat16, NamedSharding(mesh, P("data")))
+
+    def fn(p, x):
+        return layers.attention(p, x, spec, jnp.arange(512))[0]
+
+    text = jax.jit(fn).lower(params, x).compile().as_text()
+    assert ("tpu_custom_call" in text) is kernel
+
+
 def test_decode_attention_cache_4096(one_chip):
     from repro.kernels.decode_attention import ops as da_ops
     b, s, hq, hkv, hd = 8, 4096, 32, 8, 128
@@ -85,7 +116,8 @@ def test_ssd_scan_mamba2_780m_widths(one_chip):
 
 def test_lm_forward_full_width_fits_one_chip(topo):
     """The served program, granite-3-8b at published widths on a 1x1
-    slot, and the init that builds its weights there."""
+    slot, its attention on the flash kernel, and the init that builds
+    its weights there."""
     from repro.core import zoo
     mesh = jax.sharding.Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                              ("data", "model"))
@@ -97,6 +129,8 @@ def test_lm_forward_full_width_fits_one_chip(topo):
     tokens = _sds(prog.abstract_inputs[0].shape, jnp.int32, in_sh)
     assert tokens.shape == (8, 512)
     fwd = jax.jit(prog.fn).lower(weights, tokens).compile()
+    # the default attn_impl serves the flash kernel here
+    _assert_kernel(fwd)
     mem = fwd.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES, mem
