@@ -21,6 +21,7 @@ ARCH_IDS = [
     "mamba2-780m",
     "phi-3-vision-4.2b",
     "jamba-v0.1-52b",
+    "moonlight-16b-a3b",
 ]
 
 _MODULES = {
@@ -34,6 +35,7 @@ _MODULES = {
     "mamba2-780m": "mamba2_780m",
     "phi-3-vision-4.2b": "phi_3_vision_4_2b",
     "jamba-v0.1-52b": "jamba_v0_1_52b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

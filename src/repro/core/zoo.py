@@ -4,7 +4,8 @@ These are the FOS-JAX analogues of the paper's case-study accelerators:
   - mandelbrot : compute-bound fractal iteration (paper section 5.5)
   - sobel      : memory-bound 3x3 stencil (paper section 5.5)
   - matmul     : generic dense kernel (spector-style)
-  - lm_forward : granite-3-8b forward at its published widths
+  - lm_forward : an LM's forward at its published widths: granite-3-8b,
+                 or a model named by its Hugging Face keys
 
 Each builder(mesh, footprint) -> ModuleProgram.  Bigger footprints map to
 wider data-parallel slots; implementation alternatives additionally scale
@@ -107,24 +108,39 @@ def build_matmul(mesh, footprint: int, *, m: int = 512, k: int = 512,
 
 
 def build_lm_forward(mesh, footprint: int, *, reduced: bool = False,
-                     batch: int = 8, seq: int = 512) -> ModuleProgram:
-    """granite-3-8b teacher-forced forward over a [batch, seq] token chunk,
-    returning the last position's logits [batch, padded vocab].
+                     batch: int = 8, seq: int = 512,
+                     model: dict | None = None) -> ModuleProgram:
+    """Teacher-forced forward over a [batch, seq] token chunk, returning
+    the last position's logits [batch, padded vocab].  A model whose
+    routed experts report their choices (the dropless path) returns
+    {"logits": those logits, "experts": each token's experts at each MoE
+    layer, [layers, batch, seq, top_k] int32}: what a caller needs to
+    check the routing, or to replay it.
 
-    Serves `configs.granite_3_8b.SERVED` (every width as published, 16
-    layers, bf16 weights; the cuts are listed beside it).  `reduced=True`
-    serves the 2-layer REDUCED config instead, for CPU tests and examples
-    (pass it through the descriptor: `lm_forward_descriptor(reduced=True)`).
+    `model`: a configuration's Hugging Face keys (`models.hf`, which
+    refuses what the stack cannot compute), as a benchmark configuration
+    passes them.  Without it, serves granite-3-8b's
+    `configs.granite_3_8b.SERVED` (every width as published, 16 layers,
+    bf16 weights; the cuts are listed beside it); `reduced=True` serves
+    its 2-layer REDUCED config instead, for CPU tests and examples (pass
+    it through the descriptor: `lm_forward_descriptor(reduced=True)`).
     """
     from repro.configs import granite_3_8b
-    from repro.models import api, stack
+    from repro.models import api, hf, stack
 
-    cfg = granite_3_8b.REDUCED if reduced else granite_3_8b.SERVED
+    if model is not None:
+        cfg = hf.model_config(model)
+    else:
+        cfg = granite_3_8b.REDUCED if reduced else granite_3_8b.SERVED
     axis = _data_axis(mesh)
 
     def fn(params, tokens):
-        h, _ = stack.forward(params, cfg, {"tokens": tokens})
-        return stack.unembed(params, cfg, h[:, -1:])[:, 0]
+        h, _, experts = stack.forward(params, cfg, {"tokens": tokens},
+                                      with_experts=True)
+        logits = stack.unembed(params, cfg, h[:, -1:])[:, 0]
+        if experts is None:
+            return logits
+        return {"logits": logits, "experts": experts}
 
     specs = api.param_specs(cfg)
     pspecs = jax.tree.map(lambda _: P(), specs,

@@ -38,9 +38,12 @@ class MoEConfig:
     d_ff: int               # per-expert hidden width
     every: int = 1          # MoE FFN on every `every`-th layer (1 = all)
     capacity_factor: float = 1.25
-    impl: str = "dense"     # "dense" | "ep"
+    impl: str = "dense"     # "dense" | "ep" | "dropless"
     fsdp_experts: bool = False
     aux_loss_weight: float = 0.01
+    router: str = "softmax"         # "softmax" | "sigmoid_bias"
+    routed_scale: float = 1.0       # times the routed experts' sum
+    shared_d_ff: int = 0            # shared-expert SwiGLU width (0 = none)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,7 +73,14 @@ class ModelConfig:
     attn_bias: bool = False
     mlp_kind: str = "swiglu"
     norm_kind: str = "rms"          # rms | layer
+    norm_eps: float = 1e-6          # RMSNorm's epsilon
     tie_embeddings: bool = False
+    # multi-head latent attention (DeepSeek-V2/V3) when kv_lora_rank > 0:
+    # head_dim is then each query and key head's non-rotary part
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    first_dense: int = 0            # moe: dense layers before the MoE groups
     # family extensions
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
@@ -118,7 +128,19 @@ class ModelConfig:
             head_dim=self.head_dim, rope_theta=self.rope_theta,
             qk_norm=self.qk_norm, causal=True,
             use_rope=(self.family != "encdec"), bias=self.attn_bias,
-            attn_chunk=self.attn_chunk, attn_unroll=self.attn_unroll)
+            attn_chunk=self.attn_chunk, attn_unroll=self.attn_unroll,
+            norm_eps=self.norm_eps)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def mla_spec(self) -> layers.MLASpec:
+        return layers.MLASpec(
+            n_heads=self.n_heads, nope_dim=self.head_dim,
+            rope_dim=self.qk_rope_dim, v_dim=self.v_head_dim,
+            kv_lora_rank=self.kv_lora_rank, rope_theta=self.rope_theta)
 
     def layer_plan(self):
         """Returns (n_groups, per-group sub-layer plan).
@@ -133,8 +155,9 @@ class ModelConfig:
             assert self.moe is not None
             plan = [("attn", "moe" if (i % self.moe.every == 0) else "dense")
                     for i in range(self.moe.every)]
-            assert self.n_layers % self.moe.every == 0
-            return self.n_layers // self.moe.every, plan
+            n = self.n_layers - self.first_dense
+            assert n % self.moe.every == 0
+            return n // self.moe.every, plan
         if self.family == "ssm":
             return self.n_layers, [("mamba", "none")]
         if self.family == "hybrid":
@@ -205,6 +228,22 @@ def _attn_table(cfg: ModelConfig, cross: bool = False) -> dict:
     return t
 
 
+def _mla_table(cfg: ModelConfig) -> dict:
+    """MLA without a query LoRA: q_proj, kv_a_proj_with_mqa (the latent
+    and the one rotary key shared by every head), kv_a_layernorm, kv_b_proj
+    and o_proj."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    return {
+        "wq": ParamSpec((d, h * (cfg.head_dim + cfg.qk_rope_dim)),
+                        ("embed", "q_proj")),
+        "wkv_a": ParamSpec((d, r + cfg.qk_rope_dim), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), "ones"),
+        "wkv_b": ParamSpec((r, h * (cfg.head_dim + cfg.v_head_dim)),
+                           (None, "q_proj")),
+        "wo": ParamSpec((h * cfg.v_head_dim, d), ("q_proj", "embed")),
+    }
+
+
 def _mlp_table(cfg: ModelConfig) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     if cfg.mlp_kind == "swiglu":
@@ -226,7 +265,14 @@ def _mlp_table(cfg: ModelConfig) -> dict:
 def _moe_table(cfg: ModelConfig) -> dict:
     m = cfg.moe
     d = cfg.d_model
-    return {
+    t = {}
+    if m.router == "sigmoid_bias":
+        # drawn like every other tensor, not left at zero, so that choice
+        # and weight differ as in a trained checkpoint
+        t["router_bias"] = ParamSpec((m.n_experts,), (None,))
+    if m.shared_d_ff:
+        t["shared"] = _mlp_table(dataclasses.replace(cfg, d_ff=m.shared_d_ff))
+    return t | {
         "w_router": ParamSpec((d, m.n_experts), ("embed", None)),
         "w1": ParamSpec((m.n_experts, d, m.d_ff),
                         ("expert", "embed_nofsdp" if not m.fsdp_experts
@@ -272,7 +318,7 @@ def _sublayer_table(cfg: ModelConfig, mixer: str, ffn: str,
     t = {}
     t.update(_norm_table(cfg, "ln1"))
     if mixer == "attn":
-        t["attn"] = _attn_table(cfg)
+        t["attn"] = _mla_table(cfg) if cfg.mla else _attn_table(cfg)
     else:
         t["mamba"] = _mamba_table(cfg)
     if cross:
@@ -285,6 +331,10 @@ def _sublayer_table(cfg: ModelConfig, mixer: str, ffn: str,
         else:
             t["moe"] = _moe_table(cfg)
     return t
+
+
+# the sub-layer plan of a leading dense layer (`ModelConfig.first_dense`)
+DENSE_PLAN = [("attn", "dense")]
 
 
 def _stack_specs(tree: dict, n: int) -> dict:
@@ -306,6 +356,9 @@ def param_table(cfg: ModelConfig) -> dict:
                                    ("vocab", "embed"))},
         "blocks": _stack_specs(group, n_groups),
     }
+    if cfg.first_dense:
+        table["dense_blocks"] = _stack_specs(
+            {"sub0": _sublayer_table(cfg, *DENSE_PLAN[0])}, cfg.first_dense)
     table.update({"final": _norm_table(cfg, "lnf")})
     if not cfg.tie_embeddings:
         table["lm_head"] = ParamSpec((cfg.d_model, cfg.padded_vocab),

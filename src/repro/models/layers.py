@@ -88,6 +88,7 @@ class AttentionSpec:
     softmax_scale: float | None = None
     attn_chunk: int = 0          # q-block size for chunked attention (0=off)
     attn_unroll: bool = False    # python-unroll the q-block loop
+    norm_eps: float = 1e-6       # the query and key RMSNorms' epsilon
 
     @property
     def q_dim(self) -> int:
@@ -114,8 +115,8 @@ def _project_qkv(params, x, spec: AttentionSpec, positions):
     k = k.reshape(b, s, spec.n_kv_heads, spec.head_dim)
     v = v.reshape(b, s, spec.n_kv_heads, spec.head_dim)
     if spec.qk_norm:
-        q = rms_norm(q, params["q_norm"])
-        k = rms_norm(k, params["k_norm"])
+        q = rms_norm(q, params["q_norm"], spec.norm_eps)
+        k = rms_norm(k, params["k_norm"], spec.norm_eps)
     if spec.use_rope:
         q = apply_rope(q, positions, spec.rope_theta)
         k = apply_rope(k, positions, spec.rope_theta)
@@ -127,7 +128,8 @@ def _scores_mask(scores, mask):
 
 
 def _sdpa(q, k, v, spec: AttentionSpec, mask) -> jax.Array:
-    """Reference attention. q:[B,Sq,Hq,hd] k,v:[B,Sk,Hkv,hd].
+    """Reference attention. q:[B,Sq,Hq,hd] k:[B,Sk,Hkv,hd]
+    v:[B,Sk,Hkv,hd_v] (hd_v differs from hd in latent attention).
 
     GQA KV heads are repeated up to the q-head count so that *all* attention
     intermediates shard evenly by q-head over the TP axis (kv_heads is
@@ -155,7 +157,7 @@ def _sdpa(q, k, v, spec: AttentionSpec, mask) -> jax.Array:
     scores = _scores_mask(scores, mask)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-    return out.reshape(b, sq, hq, hd)
+    return out.reshape(b, sq, hq, v.shape[-1])
 
 
 def _chunked_sdpa(q, k, v, spec: AttentionSpec, q_offset, causal=True):
@@ -489,6 +491,83 @@ def attention(params, x, spec: AttentionSpec, positions,
     if spec.bias:
         y = y + params["bo"].astype(x.dtype)
     return y, new_cache
+
+
+# DeepSeek-V2/V3's kv_a_layernorm keeps RMSNorm's default epsilon,
+# whatever the configuration's rms_norm_eps (their modelling code and
+# transformers' alike)
+MLA_KV_NORM_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention without a query LoRA: each head's query
+    and key are a non-rotary part (`nope_dim`) and a rotary part
+    (`rope_dim`; the key's is one vector shared by every head); keys'
+    non-rotary parts and values come up from a `kv_lora_rank` latent."""
+    n_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_lora_rank: int
+    rope_theta: float
+
+    @property
+    def core(self) -> AttentionSpec:
+        """Attention over the assembled heads, scale (nope + rope)^-1/2."""
+        return AttentionSpec(n_heads=self.n_heads, n_kv_heads=self.n_heads,
+                             head_dim=self.nope_dim + self.rope_dim,
+                             rope_theta=self.rope_theta)
+
+
+def _rope_interleaved(x: jax.Array, positions, theta: float) -> jax.Array:
+    """Rope over the interleaved pairs (x0, x1), (x2, x3), ...: the pairs
+    are gathered into two halves, then rotated as `apply_rope` does (the
+    layout DeepSeek-V3's checkpoints give their rotary dims)."""
+    *lead, d = x.shape
+    x = jnp.swapaxes(x.reshape(*lead, d // 2, 2), -1, -2).reshape(*lead, d)
+    return apply_rope(x, positions, theta)
+
+
+def mla_attention(params, x, spec: MLASpec, positions,
+                  attn_impl: str = "auto", kv_cache=None, cache_pos=None,
+                  mesh=None):
+    """Latent attention; returns (out [B,S,D], new_cache|None).  The cache
+    holds the assembled keys and values ([B, S_max, H, nope + rope] and
+    [B, S_max, H, v_dim]), not the latent."""
+    b, s, _ = x.shape
+    h, nope, r = spec.n_heads, spec.nope_dim, spec.kv_lora_rank
+    q = jnp.einsum("bsd,dh->bsh", x, params["wq"].astype(x.dtype))
+    q = q.reshape(b, s, h, nope + spec.rope_dim)
+    kv_a = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"].astype(x.dtype))
+    latent = rms_norm(kv_a[..., :r], params["kv_norm"], MLA_KV_NORM_EPS)
+    kv = jnp.einsum("bsr,rh->bsh", latent, params["wkv_b"].astype(x.dtype))
+    kv = kv.reshape(b, s, h, nope + spec.v_dim)
+    q_pe = _rope_interleaved(q[..., nope:], positions, spec.rope_theta)
+    k_pe = _rope_interleaved(kv_a[:, :, None, r:], positions,
+                             spec.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], -1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, spec.rope_dim))], -1)
+    v = kv[..., nope:]
+    core = spec.core
+    new_cache = None
+    if kv_cache is None:
+        out = _self_attention(q, k, v, core, attn_impl, mesh)
+    else:
+        k_cache = jax.lax.dynamic_update_slice(
+            kv_cache["k"], k.astype(kv_cache["k"].dtype), (0, cache_pos, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            kv_cache["v"], v.astype(kv_cache["v"].dtype), (0, cache_pos, 0, 0))
+        if s > 1:
+            out = _sdpa(q, k, v, core, causal_mask(s, s))
+        else:
+            out = _local_cached_attention(q, k_cache, v_cache, core,
+                                          cache_pos)
+        new_cache = {"k": k_cache, "v": v_cache}
+    y = jnp.einsum("bsh,hd->bsd", out.reshape(b, s, h * spec.v_dim),
+                   params["wo"].astype(x.dtype))
+    return partition.constrain(y, ("batch", "seq", "embed_act")), new_cache
 
 
 def causal_mask(sq: int, sk: int, offset: int = 0) -> jax.Array:

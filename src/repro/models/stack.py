@@ -24,7 +24,7 @@ from repro.sharding import partition
 
 def _norm(sub, prefix, x, cfg: ModelConfig):
     if cfg.norm_kind == "rms":
-        return layers.rms_norm(x, sub[f"{prefix}_w"])
+        return layers.rms_norm(x, sub[f"{prefix}_w"], cfg.norm_eps)
     return layers.layer_norm(x, sub[f"{prefix}_w"], sub[f"{prefix}_b"])
 
 
@@ -33,7 +33,8 @@ def moe_spec(cfg: ModelConfig) -> moe_mod.MoESpec:
     return moe_mod.MoESpec(
         n_experts=m.n_experts, top_k=m.top_k, d_ff=m.d_ff,
         capacity_factor=m.capacity_factor, impl=m.impl,
-        fsdp_experts=m.fsdp_experts)
+        fsdp_experts=m.fsdp_experts, router=m.router,
+        routed_scale=m.routed_scale)
 
 
 def sinusoidal_positions(seq: int, dim: int) -> jax.Array:
@@ -65,11 +66,22 @@ def _remat(fn, cfg: ModelConfig):
 def _sublayer(sub, cfg: ModelConfig, plan_item, h, positions, *,
               cache=None, cache_pos=None, cross_kv=None, enc_out=None,
               mesh=None, batch_axes=("data",), attn_causal=True):
-    """Returns (h, new_cache, aux)."""
+    """Returns (h, new_cache, aux, experts): `experts` the MoE FFN's
+    choices [B, S, K] where it reports them (`moe.moe_ffn`), else None."""
     mixer, ffn = plan_item
     aux = jnp.zeros((), jnp.float32)
+    experts = None
     new_cache = {}
-    if mixer == "attn":
+    if mixer == "attn" and cfg.mla:
+        kv = None if cache is None else {"k": cache["k"], "v": cache["v"]}
+        y, new_kv = layers.mla_attention(
+            sub["attn"], _norm(sub, "ln1", h, cfg), cfg.mla_spec, positions,
+            attn_impl=cfg.attn_impl, kv_cache=kv, cache_pos=cache_pos,
+            mesh=mesh)
+        h = h + y
+        if new_kv is not None:
+            new_cache.update(new_kv)
+    elif mixer == "attn":
         spec = cfg.attn_spec
         if not attn_causal:
             spec = dataclasses.replace(spec, causal=False)
@@ -110,10 +122,11 @@ def _sublayer(sub, cfg: ModelConfig, plan_item, h, positions, *,
         h = h + layers.mlp(sub["mlp"], _norm(sub, "ln2", h, cfg),
                            cfg.mlp_kind)
     elif ffn == "moe":
-        y, aux = moe_mod.moe_ffn(sub["moe"], _norm(sub, "ln2", h, cfg),
-                                 moe_spec(cfg), mesh, batch_axes)
+        y, aux, experts = moe_mod.moe_ffn(
+            sub["moe"], _norm(sub, "ln2", h, cfg), moe_spec(cfg), mesh,
+            batch_axes)
         h = h + y
-    return h, new_cache, aux
+    return h, new_cache, aux, experts
 
 
 # ---------------------------------------------------------------------------
@@ -128,32 +141,40 @@ def run_stack(blocks, cfg: ModelConfig, h, positions, *, plan=None,
 
     blocks: pytree whose leaves have a leading `groups` axis.
     cache: matching pytree (leading groups axis) or None.
-    Returns (h, new_cache, aux_sum).
+    Returns (h, new_cache, aux_sum, experts): `experts` [layers, B, S, K],
+    the choices of the MoE sub-layers that report them, in layer order,
+    else None.
     """
     if plan is None:
         _, plan = cfg.layer_plan()
+    moe_subs = [f"sub{i}" for i, (_, ffn) in enumerate(plan) if ffn == "moe"]
+    blocks, per_group = moe_mod.hoist_experts(
+        blocks, moe_subs, moe_spec(cfg) if cfg.moe is not None else None)
 
     def body(carry, xs):
         hh, aux_acc = carry
         group, cache_g = xs
+        group = per_group(group)
         # sequence-parallel residual stream: the scan carry (the only
         # activation persisted per layer under remat="full") is sharded
         # over the model axis along seq when the rules say so
         hh = partition.constrain(hh, ("batch", "seq", "embed_act"))
-        new_cache_g = {}
+        new_cache_g, experts_g = {}, {}
         for i, item in enumerate(plan):
             sub = group[f"sub{i}"]
             sub_cache = None if cache_g is None else cache_g[f"sub{i}"]
             cross_kv = None
             if decode_cross and sub_cache is not None and "xk" in sub_cache:
                 cross_kv = {"xk": sub_cache["xk"], "xv": sub_cache["xv"]}
-            hh, nc, aux = _sublayer(
+            hh, nc, aux, experts = _sublayer(
                 sub, cfg, item, hh, positions, cache=sub_cache,
                 cache_pos=cache_pos, cross_kv=cross_kv, enc_out=enc_out,
                 mesh=mesh, batch_axes=batch_axes, attn_causal=attn_causal)
             new_cache_g[f"sub{i}"] = nc
+            if experts is not None:
+                experts_g[f"sub{i}"] = experts
             aux_acc = aux_acc + aux
-        return (hh, aux_acc), new_cache_g
+        return (hh, aux_acc), (new_cache_g, experts_g)
 
     body = _remat(body, cfg)
     zero = jnp.zeros((), jnp.float32)
@@ -162,26 +183,59 @@ def run_stack(blocks, cfg: ModelConfig, h, positions, *, plan=None,
         # layer appears in the HLO so cost_analysis counts it exactly)
         n_groups = jax.tree.leaves(blocks)[0].shape[0]
         carry = (h, zero)
-        caches = []
+        outs = []
         for i in range(n_groups):
             group_i = jax.tree.map(lambda x: x[i], blocks)
             cache_i = (None if cache is None
                        else jax.tree.map(lambda x: x[i], cache))
-            carry, nc = body(carry, (group_i, cache_i))
-            caches.append(nc)
+            carry, out = body(carry, (group_i, cache_i))
+            outs.append(out)
         h, aux = carry
+        new_cache, experts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         if cache is None:
-            return h, None, aux
-        new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
-        return h, new_cache, aux
+            new_cache = None
+        return h, new_cache, aux, _layer_order(experts, moe_subs)
     if cache is None:
         # lax.scan requires xs pytrees to agree; use params-only xs
         def body_nocache(carry, group):
             return body(carry, (group, None))
-        (h, aux), _ = jax.lax.scan(body_nocache, (h, zero), blocks)
-        return h, None, aux
-    (h, aux), new_cache = jax.lax.scan(body, (h, zero), (blocks, cache))
-    return h, new_cache, aux
+        (h, aux), (_, experts) = jax.lax.scan(body_nocache, (h, zero),
+                                              blocks)
+        return h, None, aux, _layer_order(experts, moe_subs)
+    (h, aux), (new_cache, experts) = jax.lax.scan(body, (h, zero),
+                                                  (blocks, cache))
+    return h, new_cache, aux, _layer_order(experts, moe_subs)
+
+
+def _layer_order(experts: dict, moe_subs: list[str]):
+    """{sub: [groups, B, S, K]} -> [layers, B, S, K] in layer order (the
+    groups' sub-layers in turn), or None where no sub-layer reported."""
+    subs = [experts[name] for name in moe_subs if name in experts]
+    if not subs:
+        return None
+    stacked = jnp.stack(subs, axis=1)
+    return stacked.reshape(-1, *stacked.shape[2:])
+
+
+def run_stacks(params, cfg: ModelConfig, h, positions, *, cache=None,
+               **kw):
+    """The leading dense layers (`cfg.first_dense`, their own scan over
+    `params["dense_blocks"]`), then the scanned groups.  With leading
+    dense layers the cache is {"dense_blocks": ..., "blocks": ...}, else
+    the groups' cache alone.  Returns (h, new_cache, aux_sum, experts), as
+    `run_stack` (the leading dense layers choose no experts)."""
+    if not cfg.first_dense:
+        return run_stack(params["blocks"], cfg, h, positions, cache=cache,
+                         **kw)
+    part = (lambda k: None) if cache is None else cache.get
+    h, dense_cache, aux0, _ = run_stack(
+        params["dense_blocks"], cfg, h, positions, plan=api.DENSE_PLAN,
+        cache=part("dense_blocks"), **kw)
+    h, cache, aux1, experts = run_stack(params["blocks"], cfg, h, positions,
+                                        cache=part("blocks"), **kw)
+    if cache is not None:
+        cache = {"dense_blocks": dense_cache, "blocks": cache}
+    return h, cache, aux0 + aux1, experts
 
 
 # ---------------------------------------------------------------------------
@@ -223,16 +277,19 @@ def _encode(params, cfg: ModelConfig, frames, mesh, batch_axes):
     h = frames.astype(cfg.compute_dtype)
     h = h + sinusoidal_positions(se, cfg.d_model).astype(cfg.compute_dtype)
     positions = jnp.arange(se)
-    h, _, _ = run_stack(params["enc_blocks"], cfg, h, positions,
+    h, _, _, _ = run_stack(params["enc_blocks"], cfg, h, positions,
                         plan=[("attn", "dense")], mesh=mesh,
                         batch_axes=batch_axes, attn_causal=False)
     return _norm(params["enc_final"], "lnf", h, cfg)
 
 
 def forward(params, cfg: ModelConfig, batch, *, mesh=None,
-            batch_axes=("data",)):
+            batch_axes=("data",), with_experts=False):
     """Training/teacher-forcing forward. batch: dict with `tokens` [B,S]
-    (+ `frames` for encdec, `patches` for vlm). Returns (h_final, aux)."""
+    (+ `frames` for encdec, `patches` for vlm). Returns (h_final, aux),
+    and with `with_experts` also the routed experts each token chose at
+    each MoE layer, [layers, B, S, K] (None where no layer reports them:
+    `run_stack`)."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     h = embed_tokens(params, cfg, tokens)
@@ -244,10 +301,11 @@ def forward(params, cfg: ModelConfig, batch, *, mesh=None,
     if cfg.family == "vlm":
         patches = batch["patches"].astype(cfg.compute_dtype)
         h = jnp.concatenate([patches, h[:, patches.shape[1]:]], axis=1)
-    h, _, aux = run_stack(params["blocks"], cfg, h, positions,
-                          enc_out=enc_out, mesh=mesh, batch_axes=batch_axes)
+    h, _, aux, experts = run_stacks(params, cfg, h, positions,
+                                    enc_out=enc_out, mesh=mesh,
+                                    batch_axes=batch_axes)
     h = _norm(params["final"], "lnf", h, cfg)
-    return h, aux
+    return (h, aux, experts) if with_experts else (h, aux)
 
 
 def _gold_logit(logits, targets):
@@ -315,13 +373,34 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int):
         is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
 
 
+def _with_dense(cfg: ModelConfig, group_tree):
+    """`group_tree(n_groups, plan)` for the scanned groups, and beside it
+    for the leading dense layers where the model has them (the cache
+    layout `run_stacks` takes)."""
+    tree = group_tree(*cfg.layer_plan())
+    if not cfg.first_dense:
+        return tree
+    return {"dense_blocks": group_tree(cfg.first_dense, api.DENSE_PLAN),
+            "blocks": tree}
+
+
 def _cache_shapes(cfg: ModelConfig, batch: int, max_len: int):
-    n_groups, plan = cfg.layer_plan()
+    return _with_dense(cfg, lambda n_groups, plan: _group_cache_shapes(
+        cfg, n_groups, plan, batch, max_len))
+
+
+def _group_cache_shapes(cfg: ModelConfig, n_groups, plan, batch: int,
+                        max_len: int):
     ms = cfg.mamba_spec if cfg.family in ("ssm", "hybrid") else None
     group = {}
     for i, (mixer, ffn) in enumerate(plan):
         sub = {}
-        if mixer == "attn":
+        if mixer == "attn" and cfg.mla:
+            kv_shape = (n_groups, batch, max_len, cfg.n_heads)
+            sub["k"] = (kv_shape + (cfg.head_dim + cfg.qk_rope_dim,),
+                        cfg.kv_dtype)
+            sub["v"] = (kv_shape + (cfg.v_head_dim,), cfg.kv_dtype)
+        elif mixer == "attn":
             kv_shape = (n_groups, batch, max_len, cfg.n_kv_heads,
                         cfg.head_dim)
             sub["k"] = (kv_shape, cfg.kv_dtype)
@@ -351,7 +430,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
 
 def cache_axis_specs(cfg: ModelConfig, enc_len: int = 0):
     """Logical axes pytree matching the cache."""
-    n_groups, plan = cfg.layer_plan()
+    return _with_dense(cfg, lambda n_groups, plan: _group_cache_axes(
+        cfg, plan))
+
+
+def _group_cache_axes(cfg: ModelConfig, plan):
     group = {}
     for i, (mixer, ffn) in enumerate(plan):
         sub = {}
@@ -387,8 +470,8 @@ def build_prefill_fn(cfg: ModelConfig, max_len: int, mesh=None,
         if cfg.family == "vlm":
             patches = batch["patches"].astype(cfg.compute_dtype)
             h = jnp.concatenate([patches, h[:, patches.shape[1]:]], axis=1)
-        h, cache, _ = run_stack(
-            params["blocks"], cfg, h, positions, cache=cache, cache_pos=0,
+        h, cache, _, _ = run_stacks(
+            params, cfg, h, positions, cache=cache, cache_pos=0,
             enc_out=enc_out, mesh=mesh, batch_axes=batch_axes)
         h = _norm(params["final"], "lnf", h, cfg)
         logits = unembed(params, cfg, h[:, -1:])[:, 0]
@@ -405,8 +488,8 @@ def build_decode_fn(cfg: ModelConfig, mesh=None, batch_axes=("data",)):
         if cfg.family == "encdec":
             h = h + jax.lax.dynamic_slice_in_dim(
                 params["dec_pos"], pos, s, 0).astype(cfg.compute_dtype)
-        h, cache, _ = run_stack(
-            params["blocks"], cfg, h, positions, cache=cache, cache_pos=pos,
+        h, cache, _, _ = run_stacks(
+            params, cfg, h, positions, cache=cache, cache_pos=pos,
             decode_cross=(cfg.family == "encdec"),
             mesh=mesh, batch_axes=batch_axes)
         h = _norm(params["final"], "lnf", h, cfg)

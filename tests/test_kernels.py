@@ -246,3 +246,55 @@ def test_flash_attention_refuses_non_causal():
                    jnp.float32)
     with pytest.raises(NotImplementedError):
         fa_ops.flash_attention(q, k, v, causal=False, interpret=True)
+
+
+def _gmm_inputs(key, n_pairs, k, n, e, n_w, dtype):
+    """A routed layout (`moe.dropless_layout`) of n_pairs rows, with one
+    crowded expert and some empty ones, and its operands."""
+    from repro.models import moe
+    from repro.kernels.moe_gmm.moe_gmm import TILE
+    ks = jax.random.split(key, 3 + n_w)
+    top_i = jax.random.randint(ks[0], (n_pairs, 1), 0, e // 2) * 2
+    top_i = top_i.at[: n_pairs // 2].set(4)
+    _, pair, _, tile_expert, n_tiles = moe.dropless_layout(
+        top_i, jnp.ones(top_i.shape), e)
+    rows = moe.dropless_rows(n_pairs, e)
+    lhs = jnp.where((pair < n_pairs)[:, None], jax.random.normal(
+        ks[1], (rows, k)), 0).astype(dtype)
+    rhs = tuple((jax.random.normal(kk, (e, k, n)) * k ** -0.5).astype(dtype)
+                for kk in ks[3:])
+    return lhs, rhs, tile_expert, n_tiles, int(n_tiles[0]) * TILE
+
+
+@pytest.mark.parametrize("n_w,scaled", [(1, False), (2, False), (2, True)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_matches_ref(n_w, scaled, dtype):
+    """The grouped-matmul kernel (interpret mode) computes XLA's
+    ragged_dot over the tiles in use; with two weights, SwiGLU's first
+    half, times each row's scale where one is given."""
+    from repro.kernels.moe_gmm import ops, ref
+    lhs, rhs, tg, nt, used = _gmm_inputs(jax.random.PRNGKey(11), 700, 256,
+                                         384, 8, n_w, dtype)
+    scale = (jax.random.uniform(jax.random.PRNGKey(13), lhs.shape[:1])
+             if scaled else None)
+    got = ops.gmm(lhs, rhs, tg, nt, scale, True)
+    want = ref.gmm_ref(lhs, rhs, tg, nt, scale)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got[:used], np.float32),
+                               np.asarray(want[:used], np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_moe_gmm_gradient_is_the_xla_paths():
+    from repro.kernels.moe_gmm import ops, ref
+    lhs, rhs, tg, nt, used = _gmm_inputs(jax.random.PRNGKey(12), 300, 128,
+                                         128, 4, 2, jnp.float32)
+
+    def loss(f):
+        return lambda x, w: jnp.sum(f(x, w, tg, nt)[:used] ** 2)
+
+    got = jax.grad(loss(lambda *a: ops.gmm(*a, None, True)), (0, 1))(lhs, rhs)
+    want = jax.grad(loss(ref.gmm_ref), (0, 1))(lhs, rhs)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-5)

@@ -114,14 +114,14 @@ def test_ssd_scan_mamba2_780m_widths(one_chip):
                                      impl="pallas").compile())
 
 
-def test_lm_forward_full_width_fits_one_chip(topo):
-    """The served program, granite-3-8b at published widths on a 1x1
-    slot, its attention on the flash kernel, and the init that builds
-    its weights there."""
+def _served_program_fits_one_chip(topo, **builder_args):
+    """`zoo.build_lm_forward`'s program and its on-slot weight init,
+    compiled for a 1x1 slot; asserts both fit the chip and returns the
+    compiled forward."""
     from repro.core import zoo
     mesh = jax.sharding.Mesh(np.array(topo.devices[:1]).reshape(1, 1),
                              ("data", "model"))
-    prog = zoo.build_lm_forward(mesh, 1)
+    prog = zoo.build_lm_forward(mesh, 1, **builder_args)
     w_sh = jax.tree.map(lambda p: NamedSharding(mesh, p), prog.weight_pspecs)
     in_sh = NamedSharding(mesh, prog.input_pspecs[0])
     weights = jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
@@ -129,8 +129,6 @@ def test_lm_forward_full_width_fits_one_chip(topo):
     tokens = _sds(prog.abstract_inputs[0].shape, jnp.int32, in_sh)
     assert tokens.shape == (8, 512)
     fwd = jax.jit(prog.fn).lower(weights, tokens).compile()
-    # the default attn_impl serves the flash kernel here
-    _assert_kernel(fwd)
     mem = fwd.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         < V5E_HBM_BYTES, mem
@@ -139,3 +137,27 @@ def test_lm_forward_full_width_fits_one_chip(topo):
         .compile().memory_analysis()
     assert init.output_size_in_bytes + init.temp_size_in_bytes \
         < V5E_HBM_BYTES, init
+    return fwd
+
+
+def test_lm_forward_full_width_fits_one_chip(topo):
+    """The served program, granite-3-8b at published widths on a 1x1
+    slot, its attention on the flash kernel, and the init that builds
+    its weights there."""
+    # the default attn_impl serves the flash kernel here
+    _assert_kernel(_served_program_fits_one_chip(topo))
+
+
+def test_moonlight_forward_full_width_fits_one_chip(topo):
+    """The `moonlight.batch` cell's program, Moonlight-16B-A3B at published
+    widths (9 of 27 layers, every expert), its routed experts on the
+    `moe_gmm` kernel, and the init that builds its 10.9 GB of weights."""
+    import json
+    from pathlib import Path
+    model = json.loads((Path(__file__).resolve().parents[1] / "bench" /
+                        "configs" / "moonlight-16b-a3b-9l.1chip.json")
+                       .read_text())
+    fwd = _served_program_fits_one_chip(topo, model=model, batch=8, seq=512)
+    text = fwd.as_text()
+    from repro.kernels.moe_gmm.moe_gmm import KERNEL_NAME
+    assert "tpu_custom_call" in text and KERNEL_NAME in text
