@@ -17,10 +17,12 @@ and one is dropless:
 
 - ``dropless``: every token-expert pair is computed once, with no
   capacity.  Pairs are sorted by expert into rows padded per expert to a
-  tile (``dropless_layout``), run through a grouped matmul (the Pallas
-  ``moe_gmm`` kernel on one TPU chip, XLA's ``ragged_dot`` elsewhere),
-  each row scaled by its pair's combine weight, then gathered back to its
-  token and summed.
+  tile (``dropless_layout``) by XLA's row gather, run through a grouped
+  matmul, each row scaled by its pair's combine weight, and each token's
+  K rows summed.  On one TPU chip the matmul is the Pallas ``moe_gmm``
+  kernel, and the ``moe_combine`` kernel copies each token's K rows by
+  DMA through their indices and sums them; elsewhere XLA's
+  ``ragged_dot`` and a second row gather do it.
 
 Routing (``route``): softmax top-k with normalised combine weights and a
 load-balancing aux loss (Switch-style), or DeepSeek-V3's sigmoid scores
@@ -291,26 +293,30 @@ def dropless_layout(top_i: jax.Array, top_w: jax.Array, n_experts: int,
             n_tiles)
 
 
-def _gmm(lhs, rhs: tuple, tile_expert, n_tiles, layer, scale, mesh):
+def _gmm(lhs, rhs: tuple, tile_expert, n_tiles, layer, scale, mesh,
+         combine=None):
     """Layer `layer` of each whole stack in `rhs` ([L, E, K, N]) against the
-    tiled rows (`scale`: None, or each output row's factor): the Pallas
-    kernel on a TPU in a program on one device,
-    which reads the layer's experts in place (the stacks viewed as L x E
-    experts); XLA's ragged_dot on any other platform, and across devices
-    (a pallas_call is not partitioned)."""
-    from repro.kernels.moe_gmm import ops, ref
+    tiled rows (`scale`, `combine`: as `ref.gmm_ref` takes them): the
+    Pallas kernels on a TPU in a program on one device, which read the
+    layer's experts in place (the stacks viewed as L x E experts) and copy
+    the rows to combine by DMA (where the kernel packs rows of that
+    width); XLA's ragged_dot and row gather on any other platform, and
+    across devices (a pallas_call is not partitioned)."""
+    from repro.kernels.moe_gmm import moe_gmm as knl, ops, ref
 
-    def kernel(lhs, rhs, tile_expert, n_tiles, layer, scale):
+    def kernel(lhs, rhs, tile_expert, n_tiles, layer, scale, combine):
         n_exp = rhs[0].shape[1]
         return ops.gmm(lhs, tuple(w.reshape(-1, *w.shape[2:]) for w in rhs),
-                       tile_expert + layer * n_exp, n_tiles, scale, False)
+                       tile_expert + layer * n_exp, n_tiles, scale, False,
+                       combine)
 
-    def xla(lhs, rhs, tile_expert, n_tiles, layer, scale):
+    def xla(lhs, rhs, tile_expert, n_tiles, layer, scale, combine):
         return ref.gmm_ref(lhs, tuple(w[layer] for w in rhs), tile_expert,
-                           n_tiles, scale)
+                           n_tiles, scale, combine)
 
-    args = (lhs, rhs, tile_expert, n_tiles, layer, scale)
-    if layers._mesh_devices(lhs, mesh) == 1:
+    args = (lhs, rhs, tile_expert, n_tiles, layer, scale, combine)
+    if layers._mesh_devices(lhs, mesh) == 1 and (
+            combine is None or knl.packs(rhs[0].shape[-1], lhs.dtype)):
         return jax.lax.platform_dependent(*args, tpu=kernel, default=xla)
     return xla(*args)
 
@@ -337,12 +343,12 @@ def moe_dropless(params, x: jax.Array, spec: MoESpec, mesh=None):
                    jnp.take(xt, pair // k, axis=0, mode="clip"), 0)
     # each row scaled by its pair's combine weight before the down
     # projection (linear in its rows), so that the combine is a plain sum
+    # of each token's K rows (on the TPU `moe_combine`'s DMAs, elsewhere
+    # XLA's row gather)
     h = _gmm(xs, (w1, w3), tile_expert, n_tiles, layer, scale, mesh)
-    ys = _gmm(h, (w2,), tile_expert, n_tiles, layer, None, mesh)
-    # gathered [K, T, D]: K outermost, so the sum needs no relayout
-    yt = jnp.sum(ys[row.reshape(t, k).T].astype(jnp.float32), axis=0)
-    return (yt.astype(x.dtype).reshape(b, s, d), aux,
-            top_i.reshape(b, s, k))
+    yt = _gmm(h, (w2,), tile_expert, n_tiles, layer, None, mesh,
+              combine=row.reshape(t, k))
+    return (yt.reshape(b, s, d), aux, top_i.reshape(b, s, k))
 
 
 _EXPERT_WEIGHTS = ("w1", "w3", "w2")

@@ -248,22 +248,26 @@ def test_flash_attention_refuses_non_causal():
         fa_ops.flash_attention(q, k, v, causal=False, interpret=True)
 
 
-def _gmm_inputs(key, n_pairs, k, n, e, n_w, dtype):
-    """A routed layout (`moe.dropless_layout`) of n_pairs rows, with one
-    crowded expert and some empty ones, and its operands."""
+def _gmm_inputs(key, n_pairs, k, n, e, n_w, dtype, top_k=1):
+    """A routed layout (`moe.dropless_layout`) of n_pairs rows, top_k
+    pairs a token, with one crowded expert and some empty ones, and its
+    operands: (lhs, rhs, tile_expert, n_tiles, rows in use, row [T, top_k],
+    pair)."""
     from repro.models import moe
     from repro.kernels.moe_gmm.moe_gmm import TILE
     ks = jax.random.split(key, 3 + n_w)
-    top_i = jax.random.randint(ks[0], (n_pairs, 1), 0, e // 2) * 2
-    top_i = top_i.at[: n_pairs // 2].set(4)
-    _, pair, _, tile_expert, n_tiles = moe.dropless_layout(
+    top_i = jax.random.randint(ks[0], (n_pairs // top_k, top_k), 0,
+                               e // 2) * 2
+    top_i = top_i.at[: n_pairs // top_k // 2, 0].set(4)
+    row, pair, _, tile_expert, n_tiles = moe.dropless_layout(
         top_i, jnp.ones(top_i.shape), e)
     rows = moe.dropless_rows(n_pairs, e)
     lhs = jnp.where((pair < n_pairs)[:, None], jax.random.normal(
         ks[1], (rows, k)), 0).astype(dtype)
     rhs = tuple((jax.random.normal(kk, (e, k, n)) * k ** -0.5).astype(dtype)
                 for kk in ks[3:])
-    return lhs, rhs, tile_expert, n_tiles, int(n_tiles[0]) * TILE
+    return (lhs, rhs, tile_expert, n_tiles, int(n_tiles[0]) * TILE,
+            row.reshape(top_i.shape), pair)
 
 
 @pytest.mark.parametrize("n_w,scaled", [(1, False), (2, False), (2, True)])
@@ -273,8 +277,8 @@ def test_moe_gmm_matches_ref(n_w, scaled, dtype):
     ragged_dot over the tiles in use; with two weights, SwiGLU's first
     half, times each row's scale where one is given."""
     from repro.kernels.moe_gmm import ops, ref
-    lhs, rhs, tg, nt, used = _gmm_inputs(jax.random.PRNGKey(11), 700, 256,
-                                         384, 8, n_w, dtype)
+    lhs, rhs, tg, nt, used, *_ = _gmm_inputs(jax.random.PRNGKey(11), 700,
+                                             256, 384, 8, n_w, dtype)
     scale = (jax.random.uniform(jax.random.PRNGKey(13), lhs.shape[:1])
              if scaled else None)
     got = ops.gmm(lhs, rhs, tg, nt, scale, True)
@@ -285,16 +289,64 @@ def test_moe_gmm_matches_ref(n_w, scaled, dtype):
                                rtol=tol, atol=tol)
 
 
-def test_moe_gmm_gradient_is_the_xla_paths():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_sums_each_tokens_rows(dtype):
+    """With `combine` the down call (interpret mode) writes its rows packed
+    and `moe_combine` sums each token's K = 6 rows: XLA's ragged_dot, row
+    gather and float32 sum."""
     from repro.kernels.moe_gmm import ops, ref
-    lhs, rhs, tg, nt, used = _gmm_inputs(jax.random.PRNGKey(12), 300, 128,
-                                         128, 4, 2, jnp.float32)
+    lhs, rhs, tg, nt, _, row, _ = _gmm_inputs(
+        jax.random.PRNGKey(15), 150 * 6, 384, 256, 8, 1, dtype, top_k=6)
+    got = ops.gmm(lhs, rhs, tg, nt, None, True, row)
+    want = ref.gmm_ref(lhs, rhs, tg, nt, None, row)
+    assert got.shape == want.shape == (150, 256)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+
+
+@pytest.mark.parametrize("n_tok,dtype", [
+    (300, jnp.bfloat16),                # 300 = 2 blocks + 44 tokens
+    (256, jnp.float32),
+    (40, jnp.bfloat16),                 # fewer tokens than a block
+])
+def test_moe_combine_is_the_xla_gather_sum(n_tok, dtype):
+    """`moe_combine` (interpret mode) sums each token's K = 6 rows as
+    XLA's row gather and float32 sum do.  Padding rows hold NaN, so a
+    read of one shows."""
+    from repro.kernels.moe_gmm import moe_gmm as knl
+    *_, row, pair = _gmm_inputs(jax.random.PRNGKey(14), n_tok * 6, 128, 128,
+                                8, 1, dtype, top_k=6)
+    ys = jnp.where((pair < n_tok * 6)[:, None], jax.random.normal(
+        jax.random.PRNGKey(18), (pair.shape[0], 256)), jnp.nan).astype(dtype)
+    got = knl.combine(knl.pack_rows(ys), row, 256, dtype, interpret=True)
+    want = jnp.sum(ys[row.T].astype(jnp.float32), axis=0).astype(dtype)
+    assert got.shape == (n_tok, 256) and np.isfinite(
+        np.asarray(got, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=1e-6,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("combined", [False, True])
+def test_moe_gmm_gradient_is_the_xla_paths(combined):
+    """The kernels' custom VJP (the reference's) against jax.grad of the
+    XLA path, on tiled rows and with each token's rows summed."""
+    from repro.kernels.moe_gmm import ops, ref
+    lhs, rhs, tg, nt, used, row, _ = _gmm_inputs(
+        jax.random.PRNGKey(12), 300, 128, 128, 4, 1 if combined else 2,
+        jnp.float32, 6 if combined else 1)
+    combine = row if combined else None
+    used = None if combined else used
 
     def loss(f):
         return lambda x, w: jnp.sum(f(x, w, tg, nt)[:used] ** 2)
 
-    got = jax.grad(loss(lambda *a: ops.gmm(*a, None, True)), (0, 1))(lhs, rhs)
-    want = jax.grad(loss(ref.gmm_ref), (0, 1))(lhs, rhs)
+    got = jax.grad(loss(lambda *a: ops.gmm(*a, None, True, combine)),
+                   (0, 1))(lhs, rhs)
+    want = jax.grad(loss(lambda *a: ref.gmm_ref(*a, None, combine)),
+                    (0, 1))(lhs, rhs)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
                                    atol=1e-5)

@@ -151,7 +151,8 @@ def test_lm_forward_full_width_fits_one_chip(topo):
 def test_moonlight_forward_full_width_fits_one_chip(topo):
     """The `moonlight.batch` cell's program, Moonlight-16B-A3B at published
     widths (9 of 27 layers, every expert), its routed experts on the
-    `moe_gmm` kernel, and the init that builds its 10.9 GB of weights."""
+    `moe_gmm` kernel and summed by the `moe_combine` kernel, and the init
+    that builds its 10.9 GB of weights."""
     import json
     from pathlib import Path
     model = json.loads((Path(__file__).resolve().parents[1] / "bench" /
@@ -159,5 +160,6 @@ def test_moonlight_forward_full_width_fits_one_chip(topo):
                        .read_text())
     fwd = _served_program_fits_one_chip(topo, model=model, batch=8, seq=512)
     text = fwd.as_text()
-    from repro.kernels.moe_gmm.moe_gmm import KERNEL_NAME
+    from repro.kernels.moe_gmm.moe_gmm import COMBINE_NAME, KERNEL_NAME
     assert "tpu_custom_call" in text and KERNEL_NAME in text
+    assert COMBINE_NAME in text
